@@ -324,6 +324,9 @@ def main(argv=None) -> Dict:
             f"--xla_force_host_platform_device_count={n_dev} "
             + os.environ.get("XLA_FLAGS", ""))
 
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
     scale = 8 if args.smoke else args.scale
     # Calibrate first: the headline legs' regime records (and any
     # unpinned routing) then price with the constants just measured.

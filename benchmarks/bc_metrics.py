@@ -229,6 +229,9 @@ def main(argv=None) -> Dict:
     args = ap.parse_args(argv)
 
     from repro.graphs.generators import from_spec
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     scale = 8 if args.smoke else args.scale
     g = from_spec("rmat", scale=scale, degree=args.degree, seed=args.seed)

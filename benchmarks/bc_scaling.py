@@ -8,8 +8,9 @@ Three layers of evidence on a CPU-only container:
 * modeled — the Theorem 5.1 α–β cost evaluated at Blue-Waters-like and
   v5e-pod scales, reproducing the shapes of Fig. 1 (strong scaling) and
   Fig. 2 (edge-weak vs vertex-weak);
-* measured (large) — the ``scaling`` record: R-MAT scale 18/20 and one
-  real public graph ingested out-of-core through
+* measured (large) — the ``scaling`` record: R-MAT scale 18/20 and a
+  generated stand-in for the SNAP ego-Facebook graph, ingested out-of-core
+  through
   ``repro.graphs.formats.load_graph`` (chunked, digest-verified), run
   through the calibrated COO fast path for sources/sec, plus
   HLO-*measured* per-device collective bytes of the compiled distributed
@@ -28,7 +29,9 @@ Three layers of evidence on a CPU-only container:
 The collective measurement needs 64 fake host devices, which must be
 configured before jax initializes — ``main`` re-invokes itself in a
 ``--comm-only`` subprocess for that step, so the measured sources/sec
-legs in the parent keep the real (single-device) topology.
+legs in the parent keep the real (single-device) topology. That child
+only compiles, on the CPU (``JAX_PLATFORMS=cpu``): the parent holds the
+accelerator, and a second process must never try to take it.
 """
 from __future__ import annotations
 
@@ -48,7 +51,6 @@ import numpy as np
 # set XLA_FLAGS before anything initializes jax (repro.spgemm's package
 # __init__ pulls it in via the autotuner).
 
-SNAP_URL = "https://snap.stanford.edu/data/facebook_combined.txt.gz"
 DATASET_DIR = "results/datasets"
 BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "baselines", "scaling.json")
@@ -138,7 +140,7 @@ def weighted_slowdown(scale=6, degree=6, nb=32) -> Dict:
 
 
 # --------------------------------------------------------------------------
-# Out-of-core datasets: cached R-MAT RCOO files + one real public graph.
+# Out-of-core datasets: cached R-MAT RCOO files + a SNAP-like text graph.
 # --------------------------------------------------------------------------
 
 
@@ -161,37 +163,21 @@ def rmat_dataset(scale: int, degree: int = 8, seed: int = 7,
     return path
 
 
-def fetch_real_graph(cache_dir: str = DATASET_DIR,
-                     timeout: float = 30.0) -> Tuple[str, bool]:
-    """The SNAP ego-Facebook edge list, downloaded-or-cached.
+def snap_like_graph(cache_dir: str = DATASET_DIR) -> str:
+    """Write (once) a generated stand-in for SNAP ego-Facebook as SNAP text.
 
-    Returns ``(path, synthesized)``. Offline (or on any download
-    failure) a synthesized stand-in of the same shape class (undirected
-    power-law, n ≈ 4k) is written instead so the leg — and its baseline
-    gate — runs everywhere; the record carries the ``synthesized`` flag.
+    The same shape class (undirected power-law, n ≈ 4k), generated from a
+    fixed seed, so the text-ingest leg needs no download.
     """
-    real = os.path.join(cache_dir, "facebook_combined.txt.gz")
-    if os.path.exists(real):
-        return real, False
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        from urllib.request import urlopen
-
-        with urlopen(SNAP_URL, timeout=timeout) as r:
-            data = r.read()
-        with open(real, "wb") as f:
-            f.write(data)
-        return real, False
-    except Exception:
-        pass
     synth = os.path.join(cache_dir, "facebook_synth.txt.gz")
     if not os.path.exists(synth):
         from repro.graphs.formats import write_edge_list
         from repro.graphs.generators import rmat
 
+        os.makedirs(cache_dir, exist_ok=True)
         g = rmat(12, 22, seed=41)  # ~4k vertices, ~88k arcs: SNAP-like
         write_edge_list(path=synth, g=g, weights=False)
-    return synth, True
+    return synth
 
 
 def ingest_leg(path: str, *, symmetrize: bool = False,
@@ -296,12 +282,13 @@ def comm_record(scale: int, nb: int = 64, iters: int = 40,
 
 def comm_record_subprocess(scale: int, nb: int = 64, iters: int = 40,
                            timeout: float = 1200.0) -> Dict:
-    """Run ``comm_record`` in a fresh process with 64 fake devices.
+    """Run ``comm_record`` in a fresh CPU-only process with 64 fake devices.
 
-    The parent's jax is already initialized on the real topology;
-    forcing fake devices there would poison the measured legs' timings
-    and the planner's routing, so the comm measurement re-invokes this
-    module with ``--comm-only``.
+    The parent's jax is already initialized on the real topology (and
+    holds the accelerator); forcing fake devices there would poison the
+    measured legs' timings and the planner's routing, so the comm
+    measurement re-invokes this module with ``--comm-only`` under
+    ``JAX_PLATFORMS=cpu``.
     """
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         out = f.name
@@ -311,6 +298,7 @@ def comm_record_subprocess(scale: int, nb: int = 64, iters: int = 40,
                "--iters", str(iters), "--out", out]
         r = subprocess.run(cmd, capture_output=True, text=True,
                            timeout=timeout,
+                           env={**os.environ, "JAX_PLATFORMS": "cpu"},
                            cwd=os.path.dirname(os.path.dirname(
                                os.path.abspath(__file__))))
         if r.returncode != 0:
@@ -352,15 +340,12 @@ def bench_scaling(smoke: bool = False, budget_s: float = 0.0,
     def over_budget() -> bool:
         return bool(budget_s) and (time.time() - t_start) > budget_s
 
-    # -- real public graph (small, runs everywhere) ---------------------
-    real_path, synthesized = fetch_real_graph()
-    res, irec = ingest_leg(real_path, symmetrize=True, chunk_edges=1 << 15)
-    irec["synthesized"] = synthesized
+    # -- SNAP-like text graph (small, runs everywhere) ------------------
+    res, irec = ingest_leg(snap_like_graph(), symmetrize=True,
+                           chunk_edges=1 << 15)
     ingests.append(irec)
     legs.append(measured_bc_leg(res, nb=32, iters=24, batches=2,
                                 baselines=baselines))
-    legs[-1]["real"] = True
-    legs[-1]["synthesized"] = synthesized
 
     # -- R-MAT scale 18 (the CI-gated big leg) --------------------------
     res, irec = ingest_leg(rmat_dataset(18), name="rmat_s18")
@@ -413,11 +398,18 @@ def main(argv=None) -> Dict:
     if args.comm_only:
         if "jax" in sys.modules:
             raise SystemExit("--comm-only must run before jax initializes")
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise SystemExit("--comm-only compiles on fake CPU devices: "
+                             "run it with JAX_PLATFORMS=cpu")
         os.environ["XLA_FLAGS"] = (
             "--xla_force_host_platform_device_count=64 "
             + os.environ.get("XLA_FLAGS", ""))
         _comm_only_main(args)
         return {}
+
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     rec = bench_scaling(smoke=args.smoke, budget_s=args.budget_s,
                         comm_scale=args.scale, comm_nb=args.nb,

@@ -237,6 +237,9 @@ def main(argv=None) -> Dict:
                     help="skip the mixed-tier QoS scenario")
     args = ap.parse_args(argv)
 
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
     scale = 8 if args.smoke else args.scale
     levels = ((1, 2, 4) if args.smoke
               else tuple(int(x) for x in args.levels.split(",")))

@@ -116,6 +116,9 @@ def bench_kernels() -> None:
 
 
 def main() -> None:
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     bench_sec52_spgemm()
     bench_table3_comm()

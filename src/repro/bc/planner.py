@@ -34,8 +34,9 @@ from repro.bc.config import Backend, ExecutionConfig
 from repro.core.metrics import metric_spec
 from repro.graphs.formats import Graph
 from repro.spgemm.autotune import choose_bc_regime
-from repro.spgemm.cost_model import (DEFAULT, Calibration, CostParams,
-                                     best_replication, load_calibration)
+from repro.spgemm.cost_model import (Calibration, CostParams,
+                                     LoadedCalibration, best_replication,
+                                     device_peaks, load_calibration)
 
 import numpy as np
 
@@ -196,22 +197,22 @@ class BCPlanner:
     ``"auto"`` loads ``results/cost_calibration.json`` (or
     ``$REPRO_BC_CALIBRATION``) fresh per plan — a benchmark that
     recalibrates mid-process is picked up via the mtime-keyed cache —
-    while an explicit ``Calibration`` (tests, what-if planning) or
-    ``None`` (force the analytic model) pins it.
+    but only when it was measured on the device kind this process runs
+    on (a refused file is named in ``plan.notes``); an explicit
+    ``Calibration`` (tests, what-if planning) or ``None`` (force the
+    analytic model) pins it. The analytic model prices with the first
+    visible device's entry in ``spgemm.cost_model.DEVICE_PEAKS``.
     """
 
     def __init__(self, *, mem_bytes: float = 4 * 2 ** 30,
-                 params: CostParams = DEFAULT,
                  calibration: Union[str, Calibration, None] = "auto"):
         self.mem_bytes = float(mem_bytes)
-        self.params = params
         self._calibration = calibration
 
-    @property
-    def calibration(self) -> Optional[Calibration]:
+    def _load_calibration(self) -> LoadedCalibration:
         if isinstance(self._calibration, str):  # "auto"
             return load_calibration()
-        return self._calibration
+        return LoadedCalibration(self._calibration)
 
     # ------------------------------------------------------------------
     def plan(self, g: Graph, query, *, mesh=None,
@@ -249,7 +250,10 @@ class BCPlanner:
         cap = (1 << 62) if query.max_samples is None else query.max_samples
         budget = n if query.mode == "exact" else min(hint, cap)
 
-        cal = self.calibration
+        cal, cal_note = self._load_calibration()
+        if cal_note is not None:
+            notes.append(cal_note)
+        peaks = device_peaks()
         # est_iters feeds the frontier-occupancy-aware CSR rate (total
         # frontier work amortizes over the sweep's iterations), so it is
         # resolved *before* any regime call.
@@ -269,15 +273,16 @@ class BCPlanner:
             # dense adjacency busts the memory budget, sizing against the
             # dense model would reject every candidate and collapse n_b
             # to the minimum even though the COO executor has room.
-            backend = Backend(choose_bc_regime(n, m, query.n_b or 64,
-                                               fill=0.5, p=p,
-                                               calibration=cal,
-                                               est_iters=est_iters)["regime"])
+            backend = Backend(choose_bc_regime(
+                n, m, query.n_b or 64, fill=0.5, vpu_ops=peaks.vpu_ops,
+                hbm_bw=peaks.hbm_bw, p=p, calibration=cal,
+                est_iters=est_iters)["regime"])
         n_b = query.n_b or min(n, choose_sample_batch(
             n, m, p=p, backend=backend.value,
             mem_bytes=self.mem_bytes, budget_hint=hint,
             calibration=cal))
-        regime = choose_bc_regime(n, m, n_b, fill=0.5, p=p, calibration=cal,
+        regime = choose_bc_regime(n, m, n_b, fill=0.5, vpu_ops=peaks.vpu_ops,
+                                  hbm_bw=peaks.hbm_bw, p=p, calibration=cal,
                                   est_iters=est_iters)
 
         # Kernel flag: an explicit pin wins; otherwise light up the Pallas
@@ -322,7 +327,8 @@ class BCPlanner:
                       if cal is not None
                       and cal.has(backend, use_kernel=use_kernel) else 0.0)
         seconds = (step_s * iters_total + overhead_s * n_batches
-                   + self.params.cost(msgs=3.0 * iters_total, bytes_=comm_bytes))
+                   + CostParams.for_peaks(peaks).cost(msgs=3.0 * iters_total,
+                                                      bytes_=comm_bytes))
         mem = self._mem_bytes(n, m, n_b, backend, placement, axes, p)
 
         execution = ExecutionConfig(backend=backend,

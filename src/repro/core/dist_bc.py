@@ -47,8 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import monoids
 from repro.core.monoids import Centpath, Multpath
@@ -112,7 +111,7 @@ def _reduce_scatter_gather(cfg, tree, reduce_fn):
     """
     red = reduce_fn(tree, cfg.model_axis)  # full reduce (pmin/pmax+psum)
     m_idx = jax.lax.axis_index(cfg.model_axis)
-    m_sz = compat.axis_size(cfg.model_axis)
+    m_sz = jax.lax.axis_size(cfg.model_axis)
 
     def scatter(v):
         blk = v.shape[1] // m_sz
@@ -127,7 +126,7 @@ def _reduce_scatter_gather(cfg, tree, reduce_fn):
 def _slice_rows(cfg, tree):
     """Keep this device's source rows: (nb_pod, x) -> (nb_pod/data, x)."""
     d_idx = jax.lax.axis_index(cfg.data_axis)
-    d_sz = compat.axis_size(cfg.data_axis)
+    d_sz = jax.lax.axis_size(cfg.data_axis)
 
     def slc(v):
         blk = v.shape[0] // d_sz
@@ -194,8 +193,8 @@ def _local_ids(cfg, n):
     v = d'·(n/D) + m·(n/(D·M)) + j with d' = c // (n/(D·M)), j = c % ….
     """
     m_idx = jax.lax.axis_index(cfg.model_axis)
-    d_sz = compat.axis_size(cfg.data_axis)
-    m_sz = compat.axis_size(cfg.model_axis)
+    d_sz = jax.lax.axis_size(cfg.data_axis)
+    m_sz = jax.lax.axis_size(cfg.model_axis)
     n_loc = n // m_sz
     sub = n // (d_sz * m_sz)
     c = jax.lax.iota(jnp.int32, n_loc)

@@ -5,7 +5,8 @@ Computes ``C = F •_(⊗,g) B`` where (for the Brandes step ``B = A^T``)
 ``C.p(i,j) = Σ_k F.p(i,k) · [tie at max]``
 ``C.c(i,j) = Σ_k [tie at max]``             (#children that reported)
 
-Same VPU/VMEM structure as ``tropical_mm``; three accumulators (max-weight,
+Same VPU/VMEM structure as ``tropical_mm`` (the frontier arrives
+transposed, so k walks the sublane axis); three accumulators (max-weight,
 tie-summed partial centrality, tie count) stay resident in VMEM across the
 k-sweep. Masking follows DESIGN.md §3: inactive frontier entries carry
 ``-inf`` and ``finite - inf = -inf`` loses the max-select, so no explicit
@@ -23,23 +24,20 @@ from jax.experimental import pallas as pl
 NEG_INF = float("-inf")
 
 
-def _kernel(fw_ref, fp_ref, b_ref, cw_ref, cp_ref, cc_ref, *, bk: int):
+def _kernel(fwt_ref, fpt_ref, b_ref, cw_ref, cp_ref, cc_ref, *, bk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         cw_ref[...] = jnp.full_like(cw_ref, NEG_INF)
         cp_ref[...] = jnp.zeros_like(cp_ref)
         cc_ref[...] = jnp.zeros_like(cc_ref)
 
-    fw = fw_ref[...]  # (bm, bk)
-    fp = fp_ref[...]  # (bm, bk)
-    b = b_ref[...]  # (bk, bn)
-
     def body(k, carry):
         accw, accp, accc = carry  # (bm, bn)
         # cand = F.w - B; -inf frontier or inf edge both yield -inf.
-        cand = fw[:, k][:, None] - b[k, :][None, :]
+        fw_k = jnp.transpose(fwt_ref[pl.ds(k, 1), :])  # (bm, 1)
+        cand = fw_k - b_ref[pl.ds(k, 1), :]
         cand = jnp.where(jnp.isnan(cand), NEG_INF, cand)  # (-inf) - (-w) guard
-        pv = fp[:, k][:, None]
+        pv = jnp.transpose(fpt_ref[pl.ds(k, 1), :])
         better = cand > accw
         tie = (cand == accw) & jnp.isfinite(cand)
         accp = jnp.where(better, jnp.broadcast_to(pv, accp.shape),
@@ -57,20 +55,21 @@ def _kernel(fw_ref, fp_ref, b_ref, cw_ref, cp_ref, cc_ref, *, bk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
-def centpath_matmul_pallas(fw: jax.Array, fp: jax.Array, b: jax.Array, *,
+def centpath_matmul_pallas(fwt: jax.Array, fpt: jax.Array, b: jax.Array, *,
                            bm: int = 128, bk: int = 128, bn: int = 128,
                            interpret: bool = False):
-    """fw/fp: (nb, n); b: (n, n2). Returns (cw, cp, cc): (nb, n2)."""
-    nb, n = fw.shape
+    """fwt/fpt: (n, nb), the frontier transposed; b: (n, n2).
+    Returns (cw, cp, cc): (nb, n2)."""
+    n, nb = fwt.shape
     n2 = b.shape[1]
-    assert nb % bm == 0 and n % bk == 0 and n2 % bn == 0, (fw.shape, b.shape)
+    assert nb % bm == 0 and n % bk == 0 and n2 % bn == 0, (fwt.shape, b.shape)
     grid = (nb // bm, n2 // bn, n // bk)
     return pl.pallas_call(
         functools.partial(_kernel, bk=bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
+            pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=[
@@ -79,9 +78,9 @@ def centpath_matmul_pallas(fw: jax.Array, fp: jax.Array, b: jax.Array, *,
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, n2), fw.dtype),
-            jax.ShapeDtypeStruct((nb, n2), fp.dtype),
-            jax.ShapeDtypeStruct((nb, n2), fw.dtype),
+            jax.ShapeDtypeStruct((nb, n2), fwt.dtype),
+            jax.ShapeDtypeStruct((nb, n2), fpt.dtype),
+            jax.ShapeDtypeStruct((nb, n2), fwt.dtype),
         ],
         interpret=interpret,
-    )(fw, fp, b)
+    )(fwt, fpt, b)

@@ -1,10 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Handles (a) padding to block multiples with monoid identities so padding is
-algebraically inert, (b) interpret-mode fallback on non-TPU backends (the
-interpreter executes the kernel body with plain JAX ops, so it lowers to
-regular HLO on CPU — used by tests and the dry-run), and (c) block-size
-selection.
+algebraically inert, (b) the transposed frontier layout the kernels read
+(k along the sublane axis), (c) interpret mode on every backend but the
+TPU (the interpreter executes the kernel body with plain JAX ops, so it
+lowers to regular HLO on the CPU — used by tests; on the TPU the compiled
+Mosaic kernel always runs), and (d) block-size selection.
 """
 from __future__ import annotations
 
@@ -19,8 +20,9 @@ from repro.kernels.tropical_mm import multpath_matmul_pallas
 INF = jnp.inf
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode is for backends without Mosaic (the CPU) only."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, rows, cols, fill):
@@ -29,7 +31,12 @@ def _pad_to(x, rows, cols, fill):
 
 
 def _pick_block(dim: int, pref: int) -> int:
-    """Largest power-of-two block <= pref that keeps padding sane."""
+    """Largest power-of-two block <= pref that keeps padding sane.
+
+    With the default ``pref`` of 128 every block is either 128 (a multiple
+    of the (8, 128) TPU tile) or, for a dim below 64, a single block that
+    covers the whole padded dim — the two shapes Mosaic accepts.
+    """
     b = pref
     while b > 8 and dim < b // 2:
         b //= 2
@@ -48,8 +55,8 @@ def multpath_matmul(fw: jax.Array, fm: jax.Array, a: jax.Array, *,
     fw_p = _pad_to(fw, NB, N, INF)
     fm_p = _pad_to(fm, NB, N, 0.0)
     a_p = _pad_to(a, N, N2, INF)
-    cw, cm = multpath_matmul_pallas(fw_p, fm_p, a_p, bm=bm, bk=bk, bn=bn,
-                                    interpret=not _on_tpu())
+    cw, cm = multpath_matmul_pallas(fw_p.T, fm_p.T, a_p, bm=bm, bk=bk, bn=bn,
+                                    interpret=_interpret())
     return cw[:nb, :n2], cm[:nb, :n2]
 
 
@@ -65,6 +72,6 @@ def centpath_matmul(fw: jax.Array, fp: jax.Array, b: jax.Array, *,
     fw_p = _pad_to(fw, NB, N, -INF)
     fp_p = _pad_to(fp, NB, N, 0.0)
     b_p = _pad_to(b, N, N2, INF)
-    cw, cp, cc = centpath_matmul_pallas(fw_p, fp_p, b_p, bm=bm, bk=bk, bn=bn,
-                                        interpret=not _on_tpu())
+    cw, cp, cc = centpath_matmul_pallas(fw_p.T, fp_p.T, b_p, bm=bm, bk=bk,
+                                        bn=bn, interpret=_interpret())
     return cw[:nb, :n2], cp[:nb, :n2], cc[:nb, :n2]

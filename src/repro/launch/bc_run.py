@@ -51,6 +51,7 @@ from repro.bc import solve as bc_solve
 from repro.core import brandes_bc, cc_ref, closeness_ref, khop_ref
 from repro.graphs.generators import from_spec
 from repro.launch.mesh import mesh_from_spec
+from repro.launch.runtime import enable_compile_cache
 from repro.train import checkpoint as ckpt_lib
 
 
@@ -190,6 +191,7 @@ def main(argv=None):
     if args.mesh and not args.approx:
         raise SystemExit("--mesh requires --approx (the exact mesh sweep "
                          "is examples/bc_distributed.py)")
+    enable_compile_cache()
 
     g = from_spec(args.graph, scale=args.scale, degree=args.degree,
                   weighted=args.weighted, seed=args.seed)

@@ -22,6 +22,7 @@ import argparse
 import time
 
 from repro.graphs.generators import from_spec
+from repro.launch.runtime import enable_compile_cache
 from repro.serve import BCGateway, BCService, GatewayConfig, start_gateway
 
 
@@ -50,6 +51,7 @@ def main(argv=None):
                     help="serve for N seconds then exit (tests/demos)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     graphs = dict(_parse_graph(s) for s in (args.graph or ["rmat:8:8"]))
     service = BCService(graphs, n_slots=args.slots, checkpoints=True)
     gateway = BCGateway(service, GatewayConfig(

@@ -23,7 +23,9 @@ is real:
 4. persist a ``spgemm.cost_model.Calibration`` to
    ``results/cost_calibration.json`` (``--out`` / ``save_calibration``),
    where ``load_calibration`` feeds it back to ``BCPlanner``,
-   ``choose_bc_regime`` and ``choose_sample_batch``.
+   ``choose_bc_regime`` and ``choose_sample_batch`` — on the same
+   device kind only: ``meta`` records the ``platform`` and
+   ``device_kind`` that measured it.
 
 ``benchmarks/bc_approx.py`` self-calibrates with ``calibrate()`` on its
 own benchmark graph before planning, so the recorded
@@ -113,6 +115,8 @@ def calibrate(g, *, nb_pair: Tuple[int, int] = (16, 64), reps: int = 2,
 
     from repro.bc.planner import BCPlanner
 
+    dev = jax.devices()[0]
+
     est_iters = BCPlanner._est_iters(g.n, weighted=bool(np.any(g.w != 1.0)),
                                      iters=0)
     rates: Dict[str, StepRates] = {}
@@ -134,7 +138,8 @@ def calibrate(g, *, nb_pair: Tuple[int, int] = (16, 64), reps: int = 2,
     return Calibration(
         rates=rates,
         meta={
-            "jax_backend": jax.default_backend(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
             "graph": {"n": int(g.n), "m": int(g.m)},
             "n_b": sorted(set(nb_pair)),
             "est_iters": int(est_iters),
@@ -164,6 +169,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     args = ap.parse_args(argv)
 
     from repro.graphs.generators import rmat
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     g = rmat(args.scale, args.avg_degree, seed=args.seed)
     nb_pair = tuple(int(x) for x in args.nb.split(","))

@@ -32,8 +32,6 @@ def run_one(arch_id: str, shape_id: str, mesh_kind: str, out_dir: str,
             policy_overrides=None) -> dict:
     import jax
 
-    from repro import compat
-
     from repro.configs import get_arch
     from repro.launch.mesh import make_production_mesh
     from repro.roofline.hlo_parse import collective_bytes
@@ -56,7 +54,7 @@ def run_one(arch_id: str, shape_id: str, mesh_kind: str, out_dir: str,
         bundle = spec.build(cell, policy)
 
     def _compile(b):
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             jitted = jax.jit(b.fn, donate_argnums=b.donate)
             lowered = jitted.lower(*b.abstract_args)
             return lowered.compile()
@@ -66,7 +64,7 @@ def run_one(arch_id: str, shape_id: str, mesh_kind: str, out_dir: str,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     trips = dict(bundle.trip_counts)
     trip_map = {"*": trips.get("while", 1)}
     coll = collective_bytes(compiled.as_text(), trip_map)
@@ -80,7 +78,7 @@ def run_one(arch_id: str, shape_id: str, mesh_kind: str, out_dir: str,
         def measure(k):
             bk = spec.build(cell, policy, unroll=True, layers_override=k)
             ck = _compile(bk)
-            cost_k = compat.cost_analysis(ck)
+            cost_k = ck.cost_analysis()
             coll_k = collective_bytes(ck.as_text(), {})
             return (float(cost_k.get("flops", 0.0)),
                     float(cost_k.get("bytes accessed", 0.0)), coll_k)
@@ -147,8 +145,10 @@ def run_all(out_dir: str, mesh_kinds, only=None, timeout=3000):
                    "--arch", arch_id, "--shape", shape_id,
                    "--mesh", mesh_kind, "--out", out_dir]
             print(f"[dryrun] spawn {' '.join(cmd[3:])}")
+            # placeholder CPU devices only: never let a child take a chip
             r = subprocess.run(cmd, capture_output=True, text=True,
-                               timeout=timeout)
+                               timeout=timeout,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             sys.stdout.write(r.stdout[-2000:])
             if r.returncode != 0:
                 failures.append((arch_id, shape_id, mesh_kind))

@@ -35,12 +35,10 @@ def _write(name, record):
 def _compile_stats(fn, args, donate=()):
     import jax
 
-    from repro import compat
-
     from repro.roofline.hlo_parse import collective_bytes
 
     compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     return {
@@ -56,8 +54,6 @@ def _compile_stats(fn, args, donate=()):
 def hillclimb_gcn2d():
     """ogb_products on the multi-pod mesh: baseline vs 2D edge partition."""
     import jax
-
-    from repro import compat
     import jax.numpy as jnp
 
     from repro.launch.mesh import make_production_mesh
@@ -74,7 +70,7 @@ def hillclimb_gcn2d():
     args = (params, ab["x"], ab["src"], ab["dst"], ab["coef"],
             ab["labels"], ab["mask"])
 
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         after = _compile_stats(jax.grad(loss2d), args)
 
     baseline_path = "results/dryrun/gcn-cora__ogb_products__multi.json"
@@ -133,13 +129,13 @@ def hillclimb_bc_blocks():
     """mfbc_paper bc_web_256k: measured block sweep + kernel tile model."""
     import jax
 
-    from repro import compat
-
     from repro.configs import get_arch
     from repro.core import dist_bc
     from repro.launch.mesh import make_production_mesh
     from repro.roofline.hlo_parse import collective_bytes
-    from repro.roofline import constants as C
+    from repro.spgemm.cost_model import DEVICE_PEAKS, TARGET_KIND
+
+    peaks = DEVICE_PEAKS[TARGET_KIND]
 
     mesh = make_production_mesh(multi_pod=True)
     n, nb, iters = 262144, 8192, 8
@@ -157,9 +153,9 @@ def hillclimb_bc_blocks():
                 sds((n, n), jnp.float32, sharding=sh[1]),
                 sds((nb,), jnp.int32, sharding=sh[2]),
                 sds((nb,), jnp.bool_, sharding=sh[3]))
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = jax.jit(step).lower(*args).compile()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         return {"block": block,
                 "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
                 "flops": float(cost.get("flops", 0.0)),
@@ -186,8 +182,8 @@ def hillclimb_bc_blocks():
         return {"tiles": (bm, bk, bn),
                 "hbm_bytes_per_relax": f + a + c,
                 "hbm_bytes_total": (f + a + c) * relaxes,
-                "t_memory_s": (f + a + c) * relaxes / C.HBM_BW,
-                "t_compute_s": ops * relaxes / 3.9e12,  # VPU rate
+                "t_memory_s": (f + a + c) * relaxes / peaks.hbm_bw,
+                "t_compute_s": ops * relaxes / peaks.vpu_ops,
                 "vmem_bytes": vmem}
 
     kmodel = [kernel_model(*t) for t in
@@ -205,7 +201,7 @@ def hillclimb_bc_blocks():
                        "the fallback and make the cell VPU-compute-bound"),
         "measured_block_sweep": sweep,
         "kernel_tile_model": kmodel,
-        "hw": {"hbm_bw": C.HBM_BW, "vpu_ops": 3.9e12},
+        "hw": {"hbm_bw": peaks.hbm_bw, "vpu_ops": peaks.vpu_ops},
     }
     _write("bcblock", rec)
     return rec

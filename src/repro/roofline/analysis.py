@@ -24,7 +24,9 @@ import json
 import os
 from typing import Dict, List, Optional
 
-from repro.roofline import constants as C
+from repro.spgemm.cost_model import DEVICE_PEAKS, TARGET_KIND
+
+PEAKS = DEVICE_PEAKS[TARGET_KIND]  # the dry-run cells model the v5e target
 
 
 def _advice(rec: Dict, dominant: str) -> str:
@@ -39,9 +41,6 @@ def _advice(rec: Dict, dominant: str) -> str:
         return ("bf16/int8 the dominant resident tensor (KV cache / "
                 "embedding rows / frontier pairs) or fuse the streaming op")
     return "compute-bound: raise MXU occupancy (bf16, larger tiles)"
-
-
-V5E_VPU_OPS = 3.9e12  # elementwise min-plus rate (the MXU cannot do it)
 
 
 def _bc_kernel_terms(rec: Dict) -> Dict:
@@ -59,19 +58,19 @@ def _bc_kernel_terms(rec: Dict) -> Dict:
     a = n_loc * n_loc * 4 * (nb_loc // bm)
     cbytes = nb_loc * n_loc * 8
     ops = 4.0 * nb_loc * n_loc * n_loc
-    return {"t_memory_s": (f + a + cbytes) * relaxes / C.HBM_BW,
-            "t_compute_s": ops * relaxes / V5E_VPU_OPS}
+    return {"t_memory_s": (f + a + cbytes) * relaxes / PEAKS.hbm_bw,
+            "t_compute_s": ops * relaxes / PEAKS.vpu_ops}
 
 
-def analyze_record(rec: Dict, *, peak_flops: float = C.PEAK_FLOPS_BF16
+def analyze_record(rec: Dict, *, peak_flops: float = PEAKS.bf16_flops
                    ) -> Dict:
     flops_dev = rec["flops_per_device"]
     bytes_dev = rec["bytes_accessed_per_device"]
     wire = rec["collectives"].get("wire_bytes", 0.0)
     operand = rec["collectives"].get("operand_bytes", 0.0)
     t_compute = flops_dev / peak_flops
-    t_memory = bytes_dev / C.HBM_BW
-    t_coll = wire / C.ICI_BW_PER_LINK
+    t_memory = bytes_dev / PEAKS.hbm_bw
+    t_coll = wire / PEAKS.ici_bw
     if rec["arch"] == "mfbc_paper":
         kt = _bc_kernel_terms(rec)
         t_compute = kt["t_compute_s"]

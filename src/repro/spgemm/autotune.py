@@ -18,7 +18,8 @@ import itertools
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from repro.spgemm.cost_model import CostParams, DEFAULT, ProblemSizes, _log2
+from repro.spgemm.cost_model import (DEFAULT, DEVICE_PEAKS, TARGET_KIND,
+                                     CostParams, ProblemSizes, _log2)
 from repro.spgemm.dist import Plan
 
 
@@ -178,8 +179,9 @@ def autotune(sizes: ProblemSizes, axes: Dict[str, int],
 
 
 def choose_bc_regime(n: int, m_edges: int, nb: int, fill: float,
-                     *, vpu_ops: float = 3.9e12,
-                     hbm_bw: float = 819e9, p: int = 256,
+                     *, vpu_ops: float = DEVICE_PEAKS[TARGET_KIND].vpu_ops,
+                     hbm_bw: float = DEVICE_PEAKS[TARGET_KIND].hbm_bw,
+                     p: int = 256,
                      calibration=None,
                      est_iters: Optional[int] = None) -> Dict[str, float]:
     """Dense/COO/CSR relax regime choice (the paper's §7 observation that

@@ -26,19 +26,71 @@ import dataclasses
 import json
 import math
 import os
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-# --- hardware constants (TPU v5e, per chip) -------------------------------
-V5E_PEAK_BF16_FLOPS = 197e12  # FLOP/s
-V5E_HBM_BW = 819e9  # bytes/s
-V5E_ICI_BW = 50e9  # bytes/s per link
-V5E_ICI_LATENCY = 1e-6  # seconds per message (α)
+
+# --- device peaks, keyed by ``jax.Device.device_kind`` --------------------
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Per-chip peaks the analytic model prices one device kind with."""
+
+    bf16_flops: float  # FLOP/s on the MXU
+    hbm_bytes: float
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per chip-to-chip link
+    ici_latency_s: float  # s per message (α)
+    vpu_ops: float  # elementwise min-plus ops/s (the VPU; the MXU cannot)
+    source: str
+
+
+#: The one peaks table. A TPU kind missing here is an error
+#: (``device_peaks``), never priced with another chip's numbers.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, hbm_bytes=16e9, hbm_bw=819e9,
+        ici_bw=1600e9 / 8 / 4, ici_latency_s=1e-6, vpu_ops=3.9e12,
+        source=("Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+                "16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip "
+                "interconnect (split over 4 links). ici_latency_s and "
+                "vpu_ops are estimates, not published figures.")),
+}
+
+#: The chip the analytic model prices where no TPU is attached (CPU
+#: test runs plan for the deployment target, not for the CPU).
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: Optional[str] = None,
+                 platform: Optional[str] = None) -> DevicePeaks:
+    """Peaks of ``kind`` (default: the first visible JAX device).
+
+    A TPU whose kind is not in ``DEVICE_PEAKS`` raises ``KeyError``; a
+    non-TPU platform prices ``TARGET_KIND``.
+    """
+    if kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        kind, platform = dev.device_kind, dev.platform
+    if kind in DEVICE_PEAKS:
+        return DEVICE_PEAKS[kind]
+    if platform is not None and platform != "tpu":
+        return DEVICE_PEAKS[TARGET_KIND]
+    raise KeyError(f"no peaks for device kind {kind!r}; add it to "
+                   f"DEVICE_PEAKS with its published source")
+
+
+_TARGET = DEVICE_PEAKS[TARGET_KIND]
 
 
 @dataclasses.dataclass(frozen=True)
 class CostParams:
-    alpha: float = V5E_ICI_LATENCY  # s per message
-    beta: float = 1.0 / V5E_ICI_BW  # s per byte
+    alpha: float = _TARGET.ici_latency_s  # s per message
+    beta: float = 1.0 / _TARGET.ici_bw  # s per byte
+
+    @classmethod
+    def for_peaks(cls, peaks: DevicePeaks) -> "CostParams":
+        return cls(alpha=peaks.ici_latency_s, beta=1.0 / peaks.ici_bw)
 
     def cost(self, msgs: float, bytes_: float) -> float:
         return self.alpha * msgs + self.beta * bytes_
@@ -154,11 +206,8 @@ def w_mm(sizes: ProblemSizes, p: int, params: CostParams = DEFAULT,
     return best, best_f
 
 
-V5E_VPU_OPS = 3.9e12  # elementwise min-plus ops/s (VPU, not MXU)
-
-
 def w_mfbc(n: int, m_edges: int, p: int, c: int, d: int, word: int = 8,
-           params: CostParams = DEFAULT, flop_rate: float = V5E_VPU_OPS
+           params: CostParams = DEFAULT, flop_rate: float = _TARGET.vpu_ops
            ) -> Dict[str, float]:
     """Theorem 5.1 cost terms for one full BC computation.
 
@@ -271,10 +320,11 @@ class Calibration:
     """Measured step-time constants, keyed by execution variant.
 
     ``rates`` maps ``variant_key(backend, use_kernel)`` →
-    ``StepRates``; ``meta`` records where the numbers came from (jax
-    backend, graph shape, batch sizes, iteration model) so a stale
-    calibration is auditable. Missing variants fall back to the
-    analytic model at the call site.
+    ``StepRates``; ``meta`` records where the numbers came from (the
+    device's ``platform`` and ``device_kind``, graph shape, batch sizes,
+    iteration model) so a stale calibration is auditable, and so
+    ``load_calibration`` can refuse one measured on another device.
+    Missing variants fall back to the analytic model at the call site.
     """
 
     rates: Dict[str, StepRates]
@@ -342,20 +392,36 @@ def calibration_path(path: Optional[str] = None) -> str:
                                   DEFAULT_CALIBRATION_PATH)
 
 
-def load_calibration(path: Optional[str] = None) -> Optional[Calibration]:
-    """Load the persisted calibration, or None when there is none.
+class LoadedCalibration(NamedTuple):
+    """``load_calibration``'s answer: the calibration to price with (or
+    None) and, when a file was found but refused, why (a plan note)."""
+
+    calibration: Optional[Calibration]
+    note: Optional[str] = None
+
+
+def load_calibration(path: Optional[str] = None, *,
+                     device: Optional[Tuple[str, str]] = None
+                     ) -> LoadedCalibration:
+    """Load the persisted calibration for this device, if there is one.
+
+    ``device`` is the ``(platform, device_kind)`` pair the plan runs on
+    (default: the first visible JAX device). A calibration whose
+    ``meta`` names another platform or device kind — or names none —
+    was measured on other hardware: it is refused, and the returned
+    note says so, so the refusal reaches the plan's notes.
 
     Cached per (absolute path, mtime): a benchmark that recalibrates
     and replans in one process sees the fresh numbers, while the
-    planner's per-plan lookups stay free. An unreadable or malformed
-    file is treated as "not calibrated" (the analytic model is always
-    a safe fallback), not an error.
+    planner's per-plan lookups stay free. An absent, unreadable or
+    malformed file is treated as "not calibrated" (the analytic model is
+    always a safe fallback), not an error.
     """
     p = os.path.abspath(calibration_path(path))
     try:
         mtime = os.path.getmtime(p)
     except OSError:
-        return None
+        return LoadedCalibration(None)
     key = (p, mtime)
     if key not in _CAL_CACHE:
         _CAL_CACHE.clear()  # one live entry: old mtimes never return
@@ -364,7 +430,21 @@ def load_calibration(path: Optional[str] = None) -> Optional[Calibration]:
                 _CAL_CACHE[key] = Calibration.from_json(json.load(f))
         except (OSError, ValueError, KeyError, TypeError):
             _CAL_CACHE[key] = None
-    return _CAL_CACHE[key]
+    cal = _CAL_CACHE[key]
+    if cal is None:
+        return LoadedCalibration(None)
+    if device is None:
+        import jax
+
+        dev = jax.devices()[0]
+        device = (dev.platform, dev.device_kind)
+    measured_on = (cal.meta.get("platform"), cal.meta.get("device_kind"))
+    if measured_on != tuple(device):
+        return LoadedCalibration(None, (
+            f"calibration {p} was measured on {measured_on[0]}/"
+            f"{measured_on[1]}, not on this {device[0]}/{device[1]}: "
+            f"not used, the analytic model prices this plan"))
+    return LoadedCalibration(cal)
 
 
 def save_calibration(cal: Calibration, path: Optional[str] = None) -> str:

@@ -38,8 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
+from jax import shard_map
 from repro.spgemm.semiring import GeneralizedSemiring, arithmetic
 
 Tree = Any
@@ -78,7 +77,7 @@ def _reduce_slice(x: Tree, axis_name: str, dim: int,
                                            tiled=True), x)
     red = sr.axis_reduce(x, axis_name)
     idx = jax.lax.axis_index(axis_name)
-    sz = compat.axis_size(axis_name)
+    sz = jax.lax.axis_size(axis_name)
 
     def slc(v):
         blk = v.shape[dim] // sz

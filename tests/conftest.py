@@ -16,6 +16,8 @@ from __future__ import annotations
 import os
 import sys
 
+import pytest
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(os.path.dirname(_HERE), "src")
 if _SRC not in sys.path:
@@ -32,3 +34,22 @@ except ImportError:
     # Bare local run: make the fallback shim importable.
     if _HERE not in sys.path:
         sys.path.insert(0, _HERE)
+
+
+@pytest.fixture
+def restore_compile_cache():
+    """Undo an entry point's ``enable_compile_cache`` after the test.
+
+    Entry points turn the persistent compilation cache on for their
+    process; called from a test, that would carry into every later test
+    on the same worker (and change their compile times).
+    """
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        yield
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()

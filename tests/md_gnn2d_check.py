@@ -5,8 +5,6 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            + os.environ.get("XLA_FLAGS", ""))
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,7 +53,7 @@ def main():
     mask = layout_features(grid, np.ones((n, 1), np.float32))[:, 0] > 0
 
     loss2d = build_gcn2d_loss(mesh, grid, n_layers=2)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         args = (params, jnp.asarray(xp), jnp.asarray(src_b),
                 jnp.asarray(dst_b), jnp.asarray(coef_b),
                 jnp.asarray(lp.astype(np.int32)), jnp.asarray(mask))

@@ -10,8 +10,6 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            + os.environ.get("XLA_FLAGS", ""))
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -57,7 +55,7 @@ def main():
     assert len(jax.devices()) == 8
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
     base = run(NO_SHARDING, shard=False)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sharded = run(make_policy(mesh), shard=True)
     print("single:", np.round(base, 5))
     print("sharded:", np.round(sharded, 5))

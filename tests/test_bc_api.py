@@ -228,7 +228,7 @@ def test_exact_solve_restricted_sources(small_graph):
     assert head.n_samples == 16 and tail.n_samples == g.n - 16
 
 
-def test_bc_run_checkpoint_resume(tmp_path):
+def test_bc_run_checkpoint_resume(tmp_path, restore_compile_cache):
     """CLI resume: cumulative λ checkpoints + persisted nb survive a kill."""
     import shutil
 

@@ -150,6 +150,10 @@ def test_overload_burst_rejects_without_starving_tight_tier():
     gw = BCGateway(svc, GatewayConfig(horizon_s=pred * 1.5,
                                       idle_sleep_s=0.05))
     srv = start_gateway(gw)
+    # Hold the solver: a batch run that finished mid-flood would rescale
+    # every later price by its observed/predicted latency (the admission
+    # correction), which on a slow host alone exceeds the horizon.
+    gw.close()
     try:
         base = srv.url
         codes = []

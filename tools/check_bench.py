@@ -176,7 +176,7 @@ def _check_scaling(sc) -> list:
     ingest = {r.get("graph"): r for r in sc.get("ingest", [])}
     if len(ingest) < 2:
         errors.append("approx.scaling: need >= 2 ingest records "
-                      f"(real graph + R-MAT), got {sorted(ingest)}")
+                      f"(SNAP-like graph + R-MAT), got {sorted(ingest)}")
     for name, r in ingest.items():
         where = f"approx.scaling.ingest[{name}]"
         if not (len(r.get("digest", "")) == 64 and r.get("n_chunks", 0) > 0):

@@ -41,7 +41,7 @@ def _module_name(py: Path) -> str:
 
 def _subpackage(dotted: str) -> str:
     """The ``repro.<pkg>`` grouping key: '' for repro itself and its
-    top-level modules (repro.compat), else the first component below it."""
+    top-level modules, else the first component below it."""
     parts = dotted.split(".")
     if len(parts) < 2 or parts[0] != ROOT_PKG:
         return ""
