@@ -138,7 +138,7 @@ def bench_backends(scale: int = 10, degree: int = 8, eps: float = 0.05,
     ``measured_seconds`` times exactly the work the plan priced —
     ``tools/check_bench.py`` gates the prediction drift at 2× and
     asserts the auto leg actually lights up a sparse fast path. The
-    pinned CSR leg's executed plan additionally carries the
+    pinned CSR leg's plan record additionally carries the solve's
     frontier-occupancy trace (per-iteration frontier nnz, compaction
     hit rate, overflow count) under ``plan.occupancy``.
     """
@@ -177,7 +177,10 @@ def bench_backends(scale: int = 10, degree: int = 8, eps: float = 0.05,
             "measured_seconds": dt,
             "predicted_seconds": out.plan.predicted_seconds,
             "prediction_ratio": out.plan.predicted_seconds / max(dt, 1e-9),
-            "plan": out.plan.to_json(),
+            # the executor's occupancy counters ride in the plan record,
+            # where tools/check_bench.py reads them
+            "plan": (out.plan.to_json() if out.occupancy is None else
+                     dict(out.plan.to_json(), occupancy=out.occupancy)),
         }
     return {
         "n": g.n,
@@ -274,7 +277,10 @@ def bench_mesh_epochs(scale: int = 10, degree: int = 8, eps: float = 0.05,
             "has_moments": res.has_moments,
             "samples_saved": budget - res.n_samples,
             "seconds": time.time() - t0,
-            "plan": out.plan.to_json(),
+            # the executor's occupancy counters ride in the plan record,
+            # where tools/check_bench.py reads them
+            "plan": (out.plan.to_json() if out.occupancy is None else
+                     dict(out.plan.to_json(), occupancy=out.occupancy)),
         }
 
     host = one("single_host", plan=host_plan)

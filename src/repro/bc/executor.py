@@ -33,17 +33,21 @@ which callers must use when sizing sample batches.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Protocol, Tuple, Union, \
     runtime_checkable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.bc.config import Backend, as_backend
 from repro.bc.planner import BCPlan, bucket_sizes
 from repro.core.adjacency import (CsrAdj, coo_adj_from_graph,
                                   csr_adj_from_graph, dense_adj_from_graph)
 from repro.core.metrics import components_graph, components_labels
+from repro.core.mfbf import TRACE_CAP
 from repro.core.mfbc import (metric_batch_moments,
                              metric_batch_moments_segmented, mfbc_batch,
                              mfbc_batch_moments,
@@ -229,6 +233,9 @@ def _slot_bucket(n_slots: int) -> int:
     return b
 
 
+_step_span = functools.partial(annotate_function, name="repro.executor.step")
+
+
 class _ExecutorBase:
     """Shared padding/bucketing half of every ``BatchExecutor``.
 
@@ -247,6 +254,10 @@ class _ExecutorBase:
     def bucket_for(self, k: int) -> int:
         return _bucket_for(k, self.buckets, self.n_b)
 
+    # A batch runs under the host span ``repro.executor.step`` (pad,
+    # dispatch, wait, pull), which a profiler trace puts on the device's
+    # clock; with no profiler session it is a no-op.
+    @_step_span
     def step(self, sources: np.ndarray, valid: np.ndarray, *,
              metric: str = "betweenness", hops: int = 0) -> Moments:
         src, val = _pad_batch(sources, valid, self.n_b)
@@ -255,6 +266,7 @@ class _ExecutorBase:
             return self._moments(src, val)
         return self._metric_moments(src, val, metric, hops)
 
+    @_step_span
     def step_sum(self, sources: np.ndarray, valid: np.ndarray, *,
                  metric: str = "betweenness", hops: int = 0) -> np.ndarray:
         src, val = _pad_batch(sources, valid, self.n_b)
@@ -314,6 +326,13 @@ class _ExecutorBase:
             f"(components runs single-host)")
 
 
+def _pull(*arrays):
+    """Device-to-host copy of a batch's outputs in one transfer, under the
+    host span ``repro.executor.pull``."""
+    with TraceAnnotation("repro.executor.pull"):
+        return jax.device_get(arrays)
+
+
 class SingleHostExecutor(_ExecutorBase):
     """One-device moments step (dense blocked, COO, or frontier-compacted
     CSR segment-op relax).
@@ -343,22 +362,25 @@ class SingleHostExecutor(_ExecutorBase):
         self._cc_adj = None
 
     def _record_occupancy(self, tr_bf, tr_br) -> None:
-        def trim(tr):
-            iters = int(tr.iters)
-            return [int(x) for x in
-                    np.asarray(tr.fnnz)[:min(iters, tr.fnnz.shape[0])]]
-        per_bf, per_br = trim(tr_bf), trim(tr_br)
+        """Fold one batch's host copies of both ``SweepTrace``s into the
+        running summary, in Python ints."""
+        def rows(tr):
+            k = min(int(tr.iters), TRACE_CAP)
+            return [[int(f), int(b), int(a)] for f, b, a in
+                    zip(tr.fnnz[:k], tr.bucket[:k], tr.arcs[:k])]
+        rows_bf, rows_br = rows(tr_bf), rows(tr_br)
         o = self._occ
         o["batches"] = o.get("batches", 0) + 1
         o["iters_bf"], o["iters_br"] = int(tr_bf.iters), int(tr_br.iters)
-        o["per_iter_bf"], o["per_iter_br"] = per_bf, per_br
-        o["fnnz_first"] = per_bf[0] if per_bf else 0
-        o["fnnz_last"] = per_bf[-1] if per_bf else 0
-        o["overflows"] = (o.get("overflows", 0) + int(tr_bf.overflows)
-                          + int(tr_br.overflows))
-        o["compact_hits"] = (o.get("compact_hits", 0)
-                             + int(tr_bf.compact_hits)
-                             + int(tr_br.compact_hits))
+        o["per_iter_bf"] = [r[0] for r in rows_bf]
+        o["per_iter_br"] = [r[0] for r in rows_br]
+        o["rows_bf"], o["rows_br"] = rows_bf, rows_br
+        o["fnnz_first"] = rows_bf[0][0] if rows_bf else 0
+        o["fnnz_last"] = rows_bf[-1][0] if rows_bf else 0
+        for key in ("overflows", "compact_hits", "frontier_arcs",
+                    "arc_slots"):
+            o[key] = (o.get(key, 0) + int(getattr(tr_bf, key))
+                      + int(getattr(tr_br, key)))
         o["relax_calls"] = (o.get("relax_calls", 0) + int(tr_bf.iters)
                             + int(tr_br.iters))
         calls = max(o["relax_calls"], 1)
@@ -367,59 +389,66 @@ class SingleHostExecutor(_ExecutorBase):
     def occupancy_summary(self):
         """Accumulated frontier-occupancy trace, or None when not traced.
 
-        Per-iteration profiles (``per_iter_bf``/``per_iter_br``, forward
-        and backward sweep frontier nnz) are from the most recent batch;
-        ``overflows``/``compact_hits``/``relax_calls``/``hit_rate``
-        accumulate over every traced batch this executor ran.
+        From the most recent batch: the per-iteration profiles of the
+        forward (``_bf``) and backward (``_br``) sweeps —
+        ``per_iter_*`` the frontier nnz, ``rows_*`` the ``[fnnz, rung,
+        arcs]`` of each relax (rung ``len(caps)`` is the full-edge-list
+        fallback). Accumulated over every traced batch this executor
+        ran: ``batches``, ``relax_calls``, ``overflows``,
+        ``compact_hits``, ``hit_rate``, ``frontier_arcs`` (arcs leaving
+        the union frontier) and ``arc_slots`` (arc slots the chosen
+        branches processed; ``frontier_arcs / arc_slots`` is the relax's
+        useful share of its work).
         """
         return dict(self._occ) if self._occ else None
 
+    def _traced(self, src, val, keep: int):
+        """One traced batch: the first ``keep`` moments, pulled with both
+        traces in one transfer; the traces go to the summary."""
+        out = mfbc_batch_moments_traced(self._adj, jnp.asarray(src),
+                                        jnp.asarray(val))
+        *stats, tr_bf, tr_br = _pull(*out[:keep], *out[3:])
+        self._record_occupancy(tr_bf, tr_br)
+        return stats
+
     def _moments(self, src, val) -> Moments:
         if self._trace:
-            s1, s2, nr, tr_bf, tr_br = mfbc_batch_moments_traced(
-                self._adj, jnp.asarray(src), jnp.asarray(val))
-            self._record_occupancy(tr_bf, tr_br)
+            s1, s2, nr = self._traced(src, val, 3)
         else:
-            s1, s2, nr = mfbc_batch_moments(self._adj, jnp.asarray(src),
-                                            jnp.asarray(val))
-        return (np.asarray(s1, np.float64), np.asarray(s2, np.float64),
-                np.asarray(nr))
+            s1, s2, nr = _pull(*mfbc_batch_moments(
+                self._adj, jnp.asarray(src), jnp.asarray(val)))
+        return s1.astype(np.float64), s2.astype(np.float64), nr
 
     def _sum(self, src, val) -> np.ndarray:
         if self._trace:
             # S1 of the moments entry point IS λ_partial, so the exact
             # sweep can ride the traced path at the cost of one extra
             # elementwise square it discards.
-            s1, _, _, tr_bf, tr_br = mfbc_batch_moments_traced(
-                self._adj, jnp.asarray(src), jnp.asarray(val))
-            self._record_occupancy(tr_bf, tr_br)
-            return np.asarray(s1, np.float64)
-        lam_b, _, _ = mfbc_batch(self._adj, jnp.asarray(src),
-                                 jnp.asarray(val))
-        return np.asarray(lam_b, np.float64)
+            (s1,) = self._traced(src, val, 1)
+        else:
+            (s1,) = _pull(mfbc_batch(self._adj, jnp.asarray(src),
+                                     jnp.asarray(val))[0])
+        return s1.astype(np.float64)
 
     def _segmented(self, src, val, sid, n_seg: int, bucket: int) -> Moments:
-        s1, s2, nr = mfbc_batch_moments_segmented(
+        s1, s2, nr = _pull(*mfbc_batch_moments_segmented(
             self._adj, jnp.asarray(src), jnp.asarray(val), jnp.asarray(sid),
-            n_slots=n_seg)
-        return (np.asarray(s1, np.float64), np.asarray(s2, np.float64),
-                np.asarray(nr))
+            n_slots=n_seg))
+        return s1.astype(np.float64), s2.astype(np.float64), nr
 
     def _metric_moments(self, src, val, metric: str, hops: int) -> Moments:
         mids = jnp.zeros(src.shape[0], jnp.int32)
-        s1, s2, nr = metric_batch_moments(
+        s1, s2, nr = _pull(*metric_batch_moments(
             self._adj, jnp.asarray(src), jnp.asarray(val), mids,
-            kinds=(metric,), hops=int(hops))
-        return (np.asarray(s1, np.float64), np.asarray(s2, np.float64),
-                np.asarray(nr))
+            kinds=(metric,), hops=int(hops)))
+        return s1.astype(np.float64), s2.astype(np.float64), nr
 
     def _metric_segmented(self, src, val, sid, mids, kinds, n_seg: int,
                           bucket: int, hops: int) -> Moments:
-        s1, s2, nr = metric_batch_moments_segmented(
+        s1, s2, nr = _pull(*metric_batch_moments_segmented(
             self._adj, jnp.asarray(src), jnp.asarray(val), jnp.asarray(sid),
-            jnp.asarray(mids), kinds=kinds, n_slots=n_seg, hops=int(hops))
-        return (np.asarray(s1, np.float64), np.asarray(s2, np.float64),
-                np.asarray(nr))
+            jnp.asarray(mids), kinds=kinds, n_slots=n_seg, hops=int(hops)))
+        return s1.astype(np.float64), s2.astype(np.float64), nr
 
     def labels(self) -> np.ndarray:
         if self._cc_adj is None:

@@ -106,12 +106,6 @@ class BCPlan:
     # above are its flat mirrors, kept for JSON and legacy readers)
     execution: Optional[ExecutionConfig] = None
     notes: Tuple[str, ...] = ()  # planner diagnostics (e.g. forced fallbacks)
-    # Frontier-occupancy trace of an *executed* plan (attached by
-    # ``solve`` after the run when the executor collected one — the
-    # frontier-sparse CSR backend's side channel): per-iteration frontier
-    # nnz of the last batch's forward/backward sweeps, compaction hit
-    # rate and overflow count. None on freshly planned (or dense/COO) plans.
-    occupancy: Optional[Dict] = None
 
     def axes_dict(self) -> Optional[Dict[str, int]]:
         return dict(self.mesh_axes) if self.mesh_axes is not None else None
@@ -125,13 +119,8 @@ class BCPlan:
         d["execution"] = (self.execution.to_json()
                           if self.execution is not None else None)
         d["notes"] = list(self.notes)
-        # Wire-schema compat: the occupancy side channel only appears on
-        # executed CSR plans — older clients (and the golden fixture)
-        # never see the key.
-        if d.get("occupancy") is None:
-            d.pop("occupancy", None)
-        # Same rule for the metric fields: default-metric plans keep the
-        # pre-metric wire schema byte-stable.
+        # Wire-schema compat: default-metric plans keep the pre-metric
+        # wire schema byte-stable.
         if d.get("metric") == "betweenness":
             d.pop("metric", None)
         if not d.get("hops"):
@@ -146,8 +135,11 @@ class BCPlan:
         → ordered pairs, ``buckets``/``notes`` lists → tuples, the
         nested ``execution`` dict → ``ExecutionConfig``), so
         ``BCPlan.from_json(p.to_json())== p`` for any planner output.
+        Records from before occupancy moved to ``BCResult`` may carry an
+        ``occupancy`` key; it is dropped.
         """
         d = dict(d)
+        d.pop("occupancy", None)
         axes = d.get("mesh_axes")
         d["mesh_axes"] = (None if axes is None
                           else tuple((k, int(v)) for k, v in axes.items()))

@@ -20,10 +20,12 @@ deprecation shims that delegate here.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
-from typing import Callable, Optional
+from typing import Callable, Dict, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.approx import sampling as S
 from repro.approx.driver import (ApproxResult, LambdaEstimator,
@@ -58,6 +60,9 @@ class BCResult:
 
     ``approx`` carries the estimator metadata (CIs, sample counts,
     convergence) for approximate queries and is ``None`` for exact ones.
+    ``occupancy`` is what the executor measured: its
+    ``occupancy_summary()`` after the run (the frontier-compacted CSR
+    step's counters), ``None`` for executors that keep none.
     """
 
     lam: np.ndarray  # (n,) λ, unnormalized ordered-pair convention
@@ -66,6 +71,7 @@ class BCResult:
     seconds: float
     n_swept: int = 0  # sources actually run through the executor
     approx: Optional[ApproxResult] = None
+    occupancy: Optional[Dict] = None
 
     def topk(self, k: int) -> np.ndarray:
         """Vertex ids of the k largest λ values, descending."""
@@ -91,6 +97,7 @@ def plan(g: Graph, query: Optional[BCQuery] = None, *, mesh=None,
     return planner.plan(g, query, mesh=mesh, n_devices=n_devices)
 
 
+@functools.partial(annotate_function, name="repro.solve")
 def solve(g: Graph, query: Optional[BCQuery] = None, *, mesh=None,
           plan: Optional[BCPlan] = None,
           executor: Optional[BatchExecutor] = None,
@@ -111,7 +118,8 @@ def solve(g: Graph, query: Optional[BCQuery] = None, *, mesh=None,
         approx mode ``cb(epoch, τ, max_halfwidth)``.
 
     Returns:
-      ``BCResult`` with λ, the executed plan and (approx) CI metadata.
+      ``BCResult`` with λ, the plan it ran (``plan`` itself when given),
+      (approx) CI metadata and the executor's occupancy counters.
     """
     query = query if query is not None else BCQuery()
     if plan is None:
@@ -128,28 +136,20 @@ def solve(g: Graph, query: Optional[BCQuery] = None, *, mesh=None,
                         seconds=time.time() - t0, n_swept=g.n)
     if query.mode == "exact":
         lam, n_swept = _run_exact(g, query, executor, sources, progress_cb)
-        return BCResult(lam=lam, plan=_with_occupancy(plan, executor),
-                        query=query, seconds=time.time() - t0,
-                        n_swept=n_swept)
+        return BCResult(lam=lam, plan=plan, query=query,
+                        seconds=time.time() - t0, n_swept=n_swept,
+                        occupancy=_occupancy(executor))
     res = _run_approx(g, query, executor, progress_cb)
-    return BCResult(lam=res.lam, plan=_with_occupancy(plan, executor),
-                    query=query, seconds=time.time() - t0,
-                    n_swept=res.n_samples, approx=res)
+    return BCResult(lam=res.lam, plan=plan, query=query,
+                    seconds=time.time() - t0, n_swept=res.n_samples,
+                    approx=res, occupancy=_occupancy(executor))
 
 
-def _with_occupancy(plan: BCPlan, executor: BatchExecutor) -> BCPlan:
-    """Attach the executor's frontier-occupancy trace to the executed plan.
-
-    Only the frontier-compacted CSR step collects a trace
-    (``SingleHostExecutor.occupancy_summary`` returns ``None``
-    otherwise), so dense/COO plans pass through *by identity* —
-    callers that cache the plan object (serving) keep their reference.
-    """
+def _occupancy(executor: BatchExecutor) -> Optional[Dict]:
+    """The executor's frontier-occupancy summary; only the
+    frontier-compacted CSR step keeps one."""
     occ_fn = getattr(executor, "occupancy_summary", None)
-    occ = occ_fn() if occ_fn is not None else None
-    if occ is None:
-        return plan
-    return dataclasses.replace(plan, occupancy=occ)
+    return occ_fn() if occ_fn is not None else None
 
 
 # ---------------------------------------------------------------- drivers
@@ -164,8 +164,10 @@ def _run_exact(g: Graph, q: BCQuery, ex: BatchExecutor, sources,
         chunk = all_sources[b * nb:(b + 1) * nb]
         # Σδ-only reduction: the sweep never needs Σδ², so skip the
         # moments overhead (3× stacked all-reduce on the mesh).
-        lam += ex.step_sum(chunk, np.ones(chunk.shape[0], bool),
-                           metric=q.metric, hops=q.hops)
+        s1 = ex.step_sum(chunk, np.ones(chunk.shape[0], bool),
+                         metric=q.metric, hops=q.hops)
+        with TraceAnnotation("repro.solve.accumulate"):
+            lam += s1
         if progress_cb is not None:
             progress_cb(b, n_batches, lam)
     return lam, int(all_sources.shape[0])
@@ -178,7 +180,8 @@ def _run_approx(g: Graph, q: BCQuery, ex: BatchExecutor,
 
     def run_batch(b: S.SampleBatch) -> None:
         s1, s2, _ = ex.step(b.sources, b.valid, metric=q.metric, hops=q.hops)
-        est.update(s1, s2, b.n_valid)
+        with TraceAnnotation("repro.solve.accumulate"):
+            est.update(s1, s2, b.n_valid)
 
     if q.strategy == "uniform":
         sampler = S.UniformSampler(n, eps=q.eps, delta=q.delta, n_b=ex.n_b,

@@ -28,13 +28,31 @@ class RelaxStats(NamedTuple):
 
     ``bucket`` is the capacity-ladder index that served the call
     (``len(caps)`` = the full-edge-list fallback, -1 = the backend has no
-    compaction at all); ``overflow`` is 1 iff the fallback ran.
+    compaction at all); ``overflow`` is 1 iff the fallback ran. ``arcs``
+    is the useful work (arcs leaving the union frontier), ``slots`` the
+    work done (the chosen rung's ``ecap``, or every arc on the fallback).
     """
 
     nnz: jax.Array  # int32 — active frontier entries seen by this relax
     arcs: jax.Array  # int32 — arc slots the frontier's ranges needed
     bucket: jax.Array  # int32 — ladder index chosen
     overflow: jax.Array  # int32 — 1 iff the full-edge-list fallback ran
+    slots: jax.Array  # int32 — arc slots the chosen branch processed
+
+
+def no_compaction() -> RelaxStats:
+    """Stats of a relax with no capacity ladder (dense, COO)."""
+    zero = jnp.int32(0)
+    return RelaxStats(zero, zero, jnp.int32(-1), zero, zero)
+
+
+def _scoped(name: str, fn):
+    """``fn`` with its ops under ``jax.named_scope(name)`` — a profiler
+    trace's ``tf_op`` then names the ``lax.switch`` branch that ran."""
+    def run(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return run
 
 
 def _gather_rows_scatter(src: jax.Array, dst: jax.Array, w: jax.Array,
@@ -86,6 +104,7 @@ class DenseAdj:
     def gather_rows(self, sources: jax.Array) -> jax.Array:
         return self.a[sources, :]
 
+    @jax.named_scope("relax.dense")
     def relax_mp(self, F: Multpath) -> Multpath:
         if self.use_kernel:
             from repro.kernels import ops as kops
@@ -94,6 +113,7 @@ class DenseAdj:
             return Multpath(w, m)
         return monoids.multpath_relax_dense(F, self.a, block=self.block)
 
+    @jax.named_scope("relax.dense")
     def relax_cp(self, F: Centpath) -> Centpath:
         if self.use_kernel:
             from repro.kernels import ops as kops
@@ -129,9 +149,11 @@ class CooAdj:
         return _gather_rows_scatter(self.src, self.dst, self.w, self.n,
                                     sources)
 
+    @jax.named_scope("relax.coo")
     def relax_mp(self, F: Multpath) -> Multpath:
         return monoids.multpath_relax_coo(F, self.src, self.dst, self.w, self.n)
 
+    @jax.named_scope("relax.coo")
     def relax_cp(self, F: Centpath) -> Centpath:
         return monoids.centpath_relax_coo(F, self.src, self.dst, self.w, self.n)
 
@@ -184,13 +206,15 @@ class CsrAdj:
         return _gather_rows_scatter(self.src, self.dst, self.w, self.n,
                                     sources)
 
-    def _pick_bucket(self, mask: jax.Array, indptr: jax.Array):
-        """Count the union-column frontier and choose the smallest fitting
-        bucket. ``nnz`` is active *columns* (vertices live in any batch
-        row — what the compacting relaxes expand), ``arcs`` their
-        incident arc total."""
+    @jax.named_scope("relax.pick")
+    def _pick_bucket(self, Fw: jax.Array, indptr: jax.Array):
+        """Count the union-column frontier (entries where ``Fw`` is
+        finite) and choose the smallest fitting bucket. ``nnz`` is active
+        *columns* (vertices live in any batch row — what the compacting
+        relaxes expand), ``arcs`` their incident arc total, ``slots`` the
+        arc slots the chosen branch processes."""
         deg = indptr[1:] - indptr[:-1]
-        colmask = jnp.any(mask, axis=0)
+        colmask = jnp.any(jnp.isfinite(Fw), axis=0)
         nnz = jnp.sum(colmask.astype(jnp.int32))
         arcs = jnp.sum(jnp.where(colmask, deg, 0)).astype(jnp.int32)
         bucket = jnp.int32(len(self.caps))
@@ -198,31 +222,39 @@ class CsrAdj:
             vcap, ecap = self.caps[i]
             fits = (nnz <= vcap) & (arcs <= ecap)
             bucket = jnp.where(fits, jnp.int32(i), bucket)
-        return nnz, arcs, bucket
+        slots = jnp.asarray([e for _, e in self.caps] + [self.src.shape[0]],
+                            jnp.int32)[bucket]
+        return nnz, arcs, bucket, slots
+
+    def _switch(self, F, indptr, rung, full_edge):
+        """Run ``F`` through the smallest fitting rung (``rung(vcap,
+        ecap)``) or, past the ladder, ``full_edge``; with its stats."""
+        nnz, arcs, bucket, slots = self._pick_bucket(F.w, indptr)
+        branches = [_scoped(f"relax.rung{i}", rung(v, e))
+                    for i, (v, e) in enumerate(self.caps)]
+        branches.append(_scoped("relax.full_edge", full_edge))
+        out = jax.lax.switch(bucket, branches, F)
+        overflow = (bucket == len(self.caps)).astype(jnp.int32)
+        return out, RelaxStats(nnz, arcs, bucket, overflow, slots)
 
     def relax_mp_stats(self, F: Multpath) -> Tuple[Multpath, RelaxStats]:
-        nnz, arcs, bucket = self._pick_bucket(jnp.isfinite(F.w), self.indptr)
-        branches = [functools.partial(
-            monoids.multpath_relax_csr, indptr=self.indptr, dst=self.dst,
-            w=self.w, n=self.n, vcap=v, ecap=e) for v, e in self.caps]
-        branches.append(lambda Fb: monoids.multpath_relax_coo(
-            Fb, self.src, self.dst, self.w, self.n))
-        out = jax.lax.switch(bucket, branches, F)
-        overflow = (bucket == len(self.caps)).astype(jnp.int32)
-        return out, RelaxStats(nnz, arcs, bucket, overflow)
+        return self._switch(
+            F, self.indptr,
+            lambda v, e: functools.partial(
+                monoids.multpath_relax_csr, indptr=self.indptr,
+                dst=self.dst, w=self.w, n=self.n, vcap=v, ecap=e),
+            lambda Fb: monoids.multpath_relax_coo(
+                Fb, self.src, self.dst, self.w, self.n))
 
     def relax_cp_stats(self, F: Centpath) -> Tuple[Centpath, RelaxStats]:
-        nnz, arcs, bucket = self._pick_bucket(jnp.isfinite(F.w),
-                                              self.indptr_in)
-        branches = [functools.partial(
-            monoids.centpath_relax_csr, indptr_in=self.indptr_in,
-            src_in=self.src_in, w_in=self.w_in, n=self.n, vcap=v, ecap=e)
-            for v, e in self.caps]
-        branches.append(lambda Fb: monoids.centpath_relax_coo(
-            Fb, self.src, self.dst, self.w, self.n))
-        out = jax.lax.switch(bucket, branches, F)
-        overflow = (bucket == len(self.caps)).astype(jnp.int32)
-        return out, RelaxStats(nnz, arcs, bucket, overflow)
+        return self._switch(
+            F, self.indptr_in,
+            lambda v, e: functools.partial(
+                monoids.centpath_relax_csr, indptr_in=self.indptr_in,
+                src_in=self.src_in, w_in=self.w_in, n=self.n, vcap=v,
+                ecap=e),
+            lambda Fb: monoids.centpath_relax_coo(
+                Fb, self.src, self.dst, self.w, self.n))
 
     def relax_mp(self, F: Multpath) -> Multpath:
         return self.relax_mp_stats(F)[0]

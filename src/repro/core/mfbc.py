@@ -20,7 +20,7 @@ from repro.core import mfbr as _mfbr
 from repro.core.adjacency import (CooAdj, CsrAdj, DenseAdj,
                                   coo_adj_from_graph, csr_adj_from_graph,
                                   dense_adj_from_graph)
-from repro.core.monoids import INF, Multpath
+from repro.core.monoids import INF
 from repro.graphs.formats import Graph
 
 
@@ -28,20 +28,32 @@ def _batch_contrib(adj, sources: jax.Array, valid: jax.Array, *,
                    iterate: str, max_iters_bf: int, max_iters_br: int):
     """Shared Algorithm 3 batch body: per-source contributions δ_s(v).
 
-    Returns (contrib, mask, Tw, Tm) with contrib (nb, n) zeroed on
-    unreachable/padding entries.
+    Returns (contrib, mask, Tw, Tm, tr_bf, tr_br) with contrib (nb, n)
+    zeroed on unreachable/padding entries and the ``SweepTrace`` of each
+    sweep (callers that do not return them leave them to dead-code
+    removal).
     """
     nb = sources.shape[0]
-    Tw, Tm = _mfbf.mfbf(adj, sources, iterate=iterate, max_iters=max_iters_bf)
-    # Exclude the t = s destination (σ(s, t, v) = 0 when t = s): mask the
-    # source's own column to (∞, 1) — the 1 keeps reciprocals safe.
-    rows = jnp.arange(nb)
-    Tw = Tw.at[rows, sources].set(INF)
-    Tm = Tm.at[rows, sources].set(1.0)
-    Zp = _mfbr.mfbr(adj, Tw, Tm, iterate=iterate, max_iters=max_iters_br)
-    mask = jnp.isfinite(Tw) & valid[:, None]
-    contrib = jnp.where(mask, Zp * Tm, 0.0)
-    return contrib, mask, Tw, Tm
+    Tw, Tm, tr_bf = _mfbf.mfbf(adj, sources, iterate=iterate,
+                               max_iters=max_iters_bf, trace=True)
+    with jax.named_scope("batch.mask"):
+        # Exclude the t = s destination (σ(s, t, v) = 0 when t = s): mask
+        # the source's own column to (∞, 1) — the 1 keeps reciprocals safe.
+        rows = jnp.arange(nb)
+        Tw = Tw.at[rows, sources].set(INF)
+        Tm = Tm.at[rows, sources].set(1.0)
+    Zp, tr_br = _mfbr.mfbr(adj, Tw, Tm, iterate=iterate,
+                           max_iters=max_iters_br, trace=True)
+    with jax.named_scope("batch.reduce"):
+        mask = jnp.isfinite(Tw) & valid[:, None]
+        contrib = jnp.where(mask, Zp * Tm, 0.0)
+    return contrib, mask, Tw, Tm, tr_bf, tr_br
+
+
+@jax.named_scope("batch.reduce")
+def _moments(contrib: jax.Array, mask: jax.Array):
+    return (jnp.sum(contrib, axis=0), jnp.sum(contrib * contrib, axis=0),
+            jnp.sum(mask, axis=0).astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("iterate", "max_iters_bf",
@@ -53,10 +65,11 @@ def mfbc_batch(adj, sources: jax.Array, valid: jax.Array, *,
 
     valid: (nb,) bool — False for padding sources (contribute nothing).
     """
-    contrib, _, Tw, Tm = _batch_contrib(adj, sources, valid, iterate=iterate,
-                                        max_iters_bf=max_iters_bf,
-                                        max_iters_br=max_iters_br)
-    return jnp.sum(contrib, axis=0), Tw, Tm
+    contrib, _, Tw, Tm, _, _ = _batch_contrib(
+        adj, sources, valid, iterate=iterate, max_iters_bf=max_iters_bf,
+        max_iters_br=max_iters_br)
+    with jax.named_scope("batch.reduce"):
+        return jnp.sum(contrib, axis=0), Tw, Tm
 
 
 @functools.partial(jax.jit, static_argnames=("iterate", "max_iters_bf",
@@ -74,26 +87,10 @@ def mfbc_batch_moments(adj, sources: jax.Array, valid: jax.Array, *,
     adaptive approximate-BC estimator (``repro.approx``), which need the
     second moment per *source sample*, not the batch sum.
     """
-    contrib, mask, _, _ = _batch_contrib(adj, sources, valid, iterate=iterate,
-                                         max_iters_bf=max_iters_bf,
-                                         max_iters_br=max_iters_br)
-    return (jnp.sum(contrib, axis=0), jnp.sum(contrib * contrib, axis=0),
-            jnp.sum(mask, axis=0).astype(jnp.int32))
-
-
-def _batch_contrib_traced(adj, sources: jax.Array, valid: jax.Array, *,
-                          max_iters_bf: int, max_iters_br: int):
-    """``_batch_contrib`` with the occupancy traces of both sweeps."""
-    nb = sources.shape[0]
-    Tw, Tm, tr_bf = _mfbf.mfbf(adj, sources, max_iters=max_iters_bf,
-                               trace=True)
-    rows = jnp.arange(nb)
-    Tw = Tw.at[rows, sources].set(INF)
-    Tm = Tm.at[rows, sources].set(1.0)
-    Zp, tr_br = _mfbr.mfbr(adj, Tw, Tm, max_iters=max_iters_br, trace=True)
-    mask = jnp.isfinite(Tw) & valid[:, None]
-    contrib = jnp.where(mask, Zp * Tm, 0.0)
-    return contrib, mask, tr_bf, tr_br
+    contrib, mask, _, _, _, _ = _batch_contrib(
+        adj, sources, valid, iterate=iterate, max_iters_bf=max_iters_bf,
+        max_iters_br=max_iters_br)
+    return _moments(contrib, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("max_iters_bf", "max_iters_br"))
@@ -103,15 +100,14 @@ def mfbc_batch_moments_traced(adj, sources: jax.Array, valid: jax.Array, *,
 
     Returns (S1, S2, n_reach, trace_bf, trace_br) where the traces are
     ``repro.core.mfbf.SweepTrace`` tuples for the forward (MFBF) and
-    backward (MFBr) sweeps of this batch. Moment outputs are computed by
-    the same relaxation sequence as the untraced entry point — the trace
-    is a read-only side channel, so values are bitwise-unchanged.
+    backward (MFBr) sweeps of this batch. Both entry points run the same
+    loop bodies, which always carry the traces; this one returns them,
+    so the moments are bitwise-unchanged.
     """
-    contrib, mask, tr_bf, tr_br = _batch_contrib_traced(
-        adj, sources, valid, max_iters_bf=max_iters_bf,
+    contrib, mask, _, _, tr_bf, tr_br = _batch_contrib(
+        adj, sources, valid, iterate="while", max_iters_bf=max_iters_bf,
         max_iters_br=max_iters_br)
-    return (jnp.sum(contrib, axis=0), jnp.sum(contrib * contrib, axis=0),
-            jnp.sum(mask, axis=0).astype(jnp.int32), tr_bf, tr_br)
+    return (*_moments(contrib, mask), tr_bf, tr_br)
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "iterate",
@@ -134,13 +130,14 @@ def mfbc_batch_moments_segmented(adj, sources: jax.Array, valid: jax.Array,
     One device call (and, on the mesh analogue, one fused all-reduce)
     therefore serves every query in the batch.
     """
-    contrib, mask, _, _ = _batch_contrib(adj, sources, valid, iterate=iterate,
-                                         max_iters_bf=max_iters_bf,
-                                         max_iters_br=max_iters_br)
+    contrib, mask, _, _, _, _ = _batch_contrib(
+        adj, sources, valid, iterate=iterate, max_iters_bf=max_iters_bf,
+        max_iters_br=max_iters_br)
     seg = functools.partial(jax.ops.segment_sum, segment_ids=slot_ids,
                             num_segments=n_slots + 1)
-    return (seg(contrib)[:n_slots], seg(contrib * contrib)[:n_slots],
-            seg(mask.astype(jnp.int32))[:n_slots])
+    with jax.named_scope("batch.reduce"):
+        return (seg(contrib)[:n_slots], seg(contrib * contrib)[:n_slots],
+                seg(mask.astype(jnp.int32))[:n_slots])
 
 
 # ==========================================================================
@@ -163,16 +160,10 @@ def _bounded_mfbf(adj, sources: jax.Array, *, hops: int):
     exactly the ≤ ``hops``-edge shortest paths; finiteness is hop-bounded
     reachability). ``hops=1`` runs zero iterations — T is the direct-edge
     row gather itself."""
-    Tw0 = adj.gather_rows(sources)
-    Tm0 = jnp.where(jnp.isfinite(Tw0), 1.0, 0.0).astype(Tw0.dtype)
-    T0 = Multpath(Tw0, Tm0)
-
-    def body(_, state):
-        T, F = state
-        T, F, _ = _mfbf._step(adj, T, F)
-        return T, F
-
-    T, _ = jax.lax.fori_loop(0, hops - 1, body, (T0, T0))
+    with jax.named_scope("mfbf"):
+        T, _, _, _ = jax.lax.fori_loop(
+            0, hops - 1, lambda _, s: _mfbf._step(adj, s),
+            _mfbf._init(adj, sources))
     return T.w, T.m
 
 

@@ -26,10 +26,17 @@ frontier. ``F'`` is active exactly where ``keep`` holds, so the carried
 count is identical to ``jnp.any(_frontier_active(F'))`` and results are
 bitwise-unchanged.
 
-``trace=True`` additionally threads a :class:`SweepTrace` through the loop:
-per-iteration frontier nnz plus, for adjacencies with frontier compaction
-(``CsrAdj``), how many relax calls a compaction bucket served and how many
-overflowed to the full edge list.
+Every sweep threads a :class:`SweepTrace` through its one loop body;
+``trace=True`` only returns it. Per iteration it records the frontier
+nnz and, for adjacencies with frontier compaction (``CsrAdj``), the rung
+that served the relax and the arcs leaving the union frontier; per sweep
+the relax calls a rung served, those that overflowed to the full edge
+list, and the arcs needed against the arc slots processed.
+
+Each stage runs under a ``jax.named_scope`` (``mfbf`` → ``init``, the
+relax scopes of ``repro.core.adjacency``, ``update``), so a profiler
+trace's ``tf_op`` names where every device op came from. Scopes are
+metadata: they leave the computation unchanged.
 """
 from __future__ import annotations
 
@@ -38,6 +45,7 @@ from typing import NamedTuple, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.core.adjacency import RelaxStats, no_compaction
 from repro.core.monoids import INF, Multpath, multpath_combine
 
 # Fixed-size per-iteration occupancy trace; iterations past the cap fold
@@ -46,44 +54,80 @@ TRACE_CAP = 64
 
 
 class SweepTrace(NamedTuple):
-    """Occupancy side-channel of one frontier sweep (MFBF or MFBr)."""
+    """Occupancy side-channel of one frontier sweep (MFBF or MFBr).
 
-    fnnz: jax.Array  # (TRACE_CAP,) int32 frontier nnz per iteration; -1 unused
+    Per-iteration slots hold -1 where unused; ``bucket`` is -1 on an
+    adjacency with no capacity ladder, ``len(caps)`` on the full-edge-list
+    fallback. ``frontier_arcs`` and ``arc_slots`` are 0 without a ladder.
+    """
+
+    fnnz: jax.Array  # (TRACE_CAP,) int32 frontier nnz entering each relax
+    bucket: jax.Array  # (TRACE_CAP,) int32 ladder rung of each relax
+    arcs: jax.Array  # (TRACE_CAP,) int32 arcs leaving the union frontier
     iters: jax.Array  # int32 — iterations executed
     overflows: jax.Array  # int32 — relax calls on the full-edge-list fallback
     compact_hits: jax.Array  # int32 — relax calls served by a capacity bucket
+    frontier_arcs: jax.Array  # int32 — Σ arcs over every iteration
+    arc_slots: jax.Array  # int32 — Σ arc slots the chosen branches processed
+
+    def record(self, nact: jax.Array, st: RelaxStats) -> "SweepTrace":
+        """The trace after one more relax, which saw ``nact`` entries."""
+        slot = jnp.minimum(self.iters, TRACE_CAP - 1)
+        hit = ((st.bucket >= 0) & (st.overflow == 0)).astype(jnp.int32)
+        return SweepTrace(self.fnnz.at[slot].set(nact),
+                          self.bucket.at[slot].set(st.bucket),
+                          self.arcs.at[slot].set(st.arcs), self.iters + 1,
+                          self.overflows + st.overflow,
+                          self.compact_hits + hit,
+                          self.frontier_arcs + st.arcs,
+                          self.arc_slots + st.slots)
 
 
 def empty_trace() -> SweepTrace:
-    return SweepTrace(jnp.full((TRACE_CAP,), -1, jnp.int32), jnp.int32(0),
-                      jnp.int32(0), jnp.int32(0))
+    unused = jnp.full((TRACE_CAP,), -1, jnp.int32)
+    zero = jnp.int32(0)
+    return SweepTrace(unused, unused, unused, zero, zero, zero, zero, zero)
 
 
 def _frontier_active(F: Multpath) -> jax.Array:
     return jnp.isfinite(F.w) & (F.m > 0)
 
 
-def _relax_with_stats(adj, F: Multpath):
-    """(C, overflow, compact_hit) — zero stats for non-compacting formats."""
+def _relax_with_stats(adj, F: Multpath) -> Tuple[Multpath, RelaxStats]:
     fn = getattr(adj, "relax_mp_stats", None)
     if fn is None:
-        return adj.relax_mp(F), jnp.int32(0), jnp.int32(0)
-    C, st = fn(F)
-    hit = ((st.bucket >= 0) & (st.overflow == 0)).astype(jnp.int32)
-    return C, st.overflow, hit
+        return adj.relax_mp(F), no_compaction()
+    return fn(F)
 
 
-def _step(adj, T: Multpath, F: Multpath
-          ) -> Tuple[Multpath, Multpath, jax.Array]:
-    """One maximal-frontier relaxation: returns (T', F', |F' active|)."""
-    C = adj.relax_mp(F)  # exactly-(j+1)-edge minimal paths from the frontier
-    T_new = multpath_combine(T, C)
-    # New frontier: candidates that match the (possibly improved) best
-    # distance. Exactly-j-edge path classes are disjoint, so multiplicities
-    # accumulate without double counting.
-    keep = (C.w == T_new.w) & jnp.isfinite(C.w) & (C.m > 0)
-    F_new = Multpath(jnp.where(keep, C.w, INF), jnp.where(keep, C.m, 0.0))
-    return T_new, F_new, jnp.sum(keep.astype(jnp.int32))
+# Loop state: (T, F, |F active|, SweepTrace).
+State = Tuple[Multpath, Multpath, jax.Array, SweepTrace]
+
+
+@jax.named_scope("init")
+def _init(adj, sources: jax.Array) -> State:
+    Tw0 = adj.gather_rows(sources)  # direct edges, (nb, n); paper line 1
+    Tm0 = jnp.where(jnp.isfinite(Tw0), 1.0, 0.0).astype(Tw0.dtype)
+    T0 = Multpath(Tw0, Tm0)
+    # paper line 2: initial frontier = exactly-1-edge paths
+    nact0 = jnp.sum(_frontier_active(T0).astype(jnp.int32))
+    return T0, T0, nact0, empty_trace()
+
+
+def _step(adj, state: State) -> State:
+    """One maximal-frontier relaxation: (T', F', |F' active|, trace')."""
+    T, F, nact, tr = state
+    C, st = _relax_with_stats(adj, F)  # exactly-(j+1)-edge minimal paths
+    with jax.named_scope("update"):
+        T_new = multpath_combine(T, C)
+        # New frontier: candidates that match the (possibly improved) best
+        # distance. Exactly-j-edge path classes are disjoint, so
+        # multiplicities accumulate without double counting.
+        keep = (C.w == T_new.w) & jnp.isfinite(C.w) & (C.m > 0)
+        F_new = Multpath(jnp.where(keep, C.w, INF),
+                         jnp.where(keep, C.m, 0.0))
+        return (T_new, F_new, jnp.sum(keep.astype(jnp.int32)),
+                tr.record(nact, st))
 
 
 def mfbf(adj, sources: jax.Array, *,
@@ -104,55 +148,15 @@ def mfbf(adj, sources: jax.Array, *,
       (Tw, Tm): (nb, n) distances and multiplicities. Unreachable = (inf, 0).
       With ``trace=True``: (Tw, Tm, SweepTrace).
     """
-    n = adj.n
-    bound = max_iters if max_iters > 0 else n - 1
-    Tw0 = adj.gather_rows(sources)  # direct edges, (nb, n); paper line 1
-    Tm0 = jnp.where(jnp.isfinite(Tw0), 1.0, 0.0).astype(Tw0.dtype)
-    T0 = Multpath(Tw0, Tm0)
-    F0 = T0  # paper line 2: initial frontier = exactly-1-edge paths
-    nact0 = jnp.sum(_frontier_active(F0).astype(jnp.int32))
-
-    if trace:
-
-        def cond(state):
-            return (state[3] > 0) & (state[2] < bound)
-
-        def body(state):
-            T, F, it, nact, tr = state
-            C, over, hit = _relax_with_stats(adj, F)
-            T_new = multpath_combine(T, C)
-            keep = (C.w == T_new.w) & jnp.isfinite(C.w) & (C.m > 0)
-            F_new = Multpath(jnp.where(keep, C.w, INF),
-                             jnp.where(keep, C.m, 0.0))
-            slot = jnp.minimum(it, TRACE_CAP - 1)
-            tr = SweepTrace(tr.fnnz.at[slot].set(nact), it + 1,
-                            tr.overflows + over, tr.compact_hits + hit)
-            return (T_new, F_new, it + 1,
-                    jnp.sum(keep.astype(jnp.int32)), tr)
-
-        T, _, _, _, tr = jax.lax.while_loop(
-            cond, body, (T0, F0, jnp.int32(0), nact0, empty_trace()))
-        return T.w, T.m, tr
-
-    if iterate == "while":
-
-        def cond(state):
-            return (state[3] > 0) & (state[2] < bound)
-
-        def body(state):
-            T, F, it, _ = state
-            T, F, nact = _step(adj, T, F)
-            return T, F, it + 1, nact
-
-        T, _, _, _ = jax.lax.while_loop(cond, body,
-                                        (T0, F0, jnp.int32(0), nact0))
-    else:
-
-        def body(_, state):
-            T, F = state
-            T, F, _ = _step(adj, T, F)
-            return T, F
-
-        T, _ = jax.lax.fori_loop(0, bound, body, (T0, F0))
-
-    return T.w, T.m
+    bound = max_iters if max_iters > 0 else adj.n - 1
+    with jax.named_scope("mfbf"):
+        state = _init(adj, sources)
+        if iterate == "while":
+            state = jax.lax.while_loop(
+                lambda s: (s[2] > 0) & (s[3].iters < bound),
+                lambda s: _step(adj, s), state)
+        else:
+            state = jax.lax.fori_loop(0, bound, lambda _, s: _step(adj, s),
+                                      state)
+    T, _, _, tr = state
+    return (T.w, T.m, tr) if trace else (T.w, T.m)

@@ -28,7 +28,8 @@ from typing import Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.core.mfbf import TRACE_CAP, SweepTrace, empty_trace
+from repro.core.adjacency import RelaxStats, no_compaction
+from repro.core.mfbf import SweepTrace, empty_trace
 from repro.core.monoids import INF, Centpath
 
 
@@ -38,29 +39,47 @@ def _seed_frontier(Tw, Tm, Zp, newly):
     return Centpath(Fw, Fp, jnp.where(newly, 1.0, 0.0))
 
 
-def _relax_with_stats(adj, F: Centpath):
-    """(P, overflow, compact_hit) — zero stats for non-compacting formats."""
+def _relax_with_stats(adj, F: Centpath) -> Tuple[Centpath, RelaxStats]:
     fn = getattr(adj, "relax_cp_stats", None)
     if fn is None:
-        return adj.relax_cp(F), jnp.int32(0), jnp.int32(0)
-    P, st = fn(F)
-    hit = ((st.bucket >= 0) & (st.overflow == 0)).astype(jnp.int32)
-    return P, st.overflow, hit
+        return adj.relax_cp(F), no_compaction()
+    return fn(F)
 
 
-def _step(adj, Tw, Tm, finite, state):
-    """One back-prop round; the last element of the returned state is the
+# Loop state: (Zp, c, done, F, |F|, SweepTrace).
+State = Tuple[jax.Array, jax.Array, jax.Array, Centpath, jax.Array,
+              SweepTrace]
+
+
+def _step(adj, Tw, Tm, finite, state: State) -> State:
+    """One back-prop round; ``|F|`` of the returned state is the
     population of the next frontier (vertices newly retired this round) —
     the while cond reads it instead of re-reducing ``F.c`` over (nb, n)."""
-    Zp, c, done, F, _ = state
-    P = adj.relax_cp(F)  # contributions shifted back along arcs
-    contrib = (P.w == Tw) & finite & (P.c > 0)
-    Zp = Zp + jnp.where(contrib, P.p, 0.0)
-    c = c - jnp.where(contrib, P.c.astype(c.dtype), 0)
-    newly = finite & (c == 0) & (~done)
-    F = _seed_frontier(Tw, Tm, Zp, newly)
-    done = done | newly
-    return Zp, c, done, F, jnp.sum(newly.astype(jnp.int32))
+    Zp, c, done, F, nact, tr = state
+    P, st = _relax_with_stats(adj, F)  # contributions shifted back along arcs
+    with jax.named_scope("update"):
+        contrib = (P.w == Tw) & finite & (P.c > 0)
+        Zp = Zp + jnp.where(contrib, P.p, 0.0)
+        c = c - jnp.where(contrib, P.c.astype(c.dtype), 0)
+        newly = finite & (c == 0) & (~done)
+        F = _seed_frontier(Tw, Tm, Zp, newly)
+        done = done | newly
+        return (Zp, c, done, F, jnp.sum(newly.astype(jnp.int32)),
+                tr.record(nact, st))
+
+
+@jax.named_scope("init")
+def _init(adj, Tw: jax.Array, Tm: jax.Array):
+    """(Tm_safe, finite, state0): the seed frontier is every reachable
+    vertex with no SP-DAG child (paper Algorithm 2 lines 1–2)."""
+    finite = jnp.isfinite(Tw)
+    Tm_safe = jnp.where(Tm > 0, Tm, 1.0)  # the paper's (∞, 1) reciprocal guard
+    c0 = adj.count_sp_children(Tw)
+    Zp0 = jnp.zeros_like(Tw)
+    seed = finite & (c0 == 0)
+    return Tm_safe, finite, (Zp0, c0, seed,
+                             _seed_frontier(Tw, Tm_safe, Zp0, seed),
+                             jnp.sum(seed.astype(jnp.int32)), empty_trace())
 
 
 def mfbr(adj, Tw: jax.Array, Tm: jax.Array, *,
@@ -68,62 +87,19 @@ def mfbr(adj, Tw: jax.Array, Tm: jax.Array, *,
          max_iters: int = 0, trace: bool = False):
     """Back-propagate centrality factors. Returns ``Zp`` with
     ``Zp[s, v] = ζ(s, v)`` (0 for unreachable/masked vertices).
-    With ``trace=True``: (Zp, SweepTrace) — see ``repro.core.mfbf``."""
-    n = adj.n
-    bound = max_iters if max_iters > 0 else n - 1
-    finite = jnp.isfinite(Tw)
-    Tm_safe = jnp.where(Tm > 0, Tm, 1.0)  # the paper's (∞, 1) reciprocal guard
-    c0 = adj.count_sp_children(Tw)
-    Zp0 = jnp.zeros_like(Tw)
-    seed = finite & (c0 == 0)
-    F0 = _seed_frontier(Tw, Tm_safe, Zp0, seed)
-    nact0 = jnp.sum(seed.astype(jnp.int32))
-    state0 = (Zp0, c0, seed, F0, nact0)
+    With ``trace=True``: (Zp, SweepTrace) — see ``repro.core.mfbf``;
+    the stages run under the scope ``mfbr`` as MFBF's run under ``mfbf``."""
+    bound = max_iters if max_iters > 0 else adj.n - 1
+    with jax.named_scope("mfbr"):
+        Tm_safe, finite, state = _init(adj, Tw, Tm)
 
-    if trace:
-
-        def cond_t(carry):
-            st, it, _ = carry
-            return (st[4] > 0) & (it < bound)
-
-        def body_t(carry):
-            st, it, tr = carry
-            Zp, c, done, F, nact = st
-            P, over, hit = _relax_with_stats(adj, F)
-            contrib = (P.w == Tw) & finite & (P.c > 0)
-            Zp = Zp + jnp.where(contrib, P.p, 0.0)
-            c = c - jnp.where(contrib, P.c.astype(c.dtype), 0)
-            newly = finite & (c == 0) & (~done)
-            F = _seed_frontier(Tw, Tm_safe, Zp, newly)
-            done = done | newly
-            slot = jnp.minimum(it, TRACE_CAP - 1)
-            tr = SweepTrace(tr.fnnz.at[slot].set(nact), it + 1,
-                            tr.overflows + over, tr.compact_hits + hit)
-            n_new = jnp.sum(newly.astype(jnp.int32))
-            return (Zp, c, done, F, n_new), it + 1, tr
-
-        (st, _, tr) = jax.lax.while_loop(cond_t, body_t,
-                                         (state0, jnp.int32(0),
-                                          empty_trace()))
-        return st[0], tr
-
-    if iterate == "while":
-
-        def cond_f(carry):
-            st, it = carry
-            return (st[4] > 0) & (it < bound)
-
-        def body_f(carry):
-            st, it = carry
-            return _step(adj, Tw, Tm_safe, finite, st), it + 1
-
-        (Zp, _, _, _, _), _ = jax.lax.while_loop(cond_f, body_f,
-                                                 (state0, jnp.int32(0)))
-    else:
-
-        def body(_, st):
+        def body(st):
             return _step(adj, Tw, Tm_safe, finite, st)
 
-        Zp, _, _, _, _ = jax.lax.fori_loop(0, bound, body, state0)
-
-    return Zp
+        if iterate == "while":
+            state = jax.lax.while_loop(
+                lambda st: (st[4] > 0) & (st[5].iters < bound), body, state)
+        else:
+            state = jax.lax.fori_loop(0, bound, lambda _, st: body(st), state)
+    Zp, tr = state[0], state[5]
+    return (Zp, tr) if trace else Zp

@@ -472,29 +472,33 @@ def test_fused_equals_unfused_per_backend(small_graph):
 # --------------------------------------------- frontier-sparse CSR backend
 def test_csr_solve_attaches_occupancy_trace(small_graph):
     """A pinned-CSR solve records the frontier-occupancy side channel on
-    the executed plan; dense/COO plans stay untouched (and pass through
+    its result; the plan stays the decision alone (and passes through
     solve by identity — see test_solve_reuses_prebuilt_executor)."""
     g, ref = small_graph
     q = BCQuery(mode="exact", n_b=16,
                 execution=ExecutionConfig(backend="csr"))
-    res = solve(g, q)
+    pl = plan(g, q, n_devices=1)
+    res = solve(g, q, plan=pl)
     np.testing.assert_allclose(res.lam, ref, rtol=1e-4, atol=1e-6)
-    occ = res.plan.occupancy
+    assert res.plan is pl
+    occ = res.occupancy
     assert occ is not None and occ["batches"] >= 1
     assert occ["per_iter_bf"] and occ["relax_calls"] > 0
     assert occ["fnnz_first"] >= occ["fnnz_last"]
     assert 0.0 <= occ["hit_rate"] <= 1.0
-    # occupancy survives the JSON artifact round-trip
+    assert 0 < occ["frontier_arcs"] <= occ["arc_slots"]
+    # occupancy is plain JSON, and the plan's wire form never carries it
     from repro.bc.planner import BCPlan
+    assert json.loads(json.dumps(occ)) == occ
     d = json.loads(json.dumps(res.plan.to_json()))
-    assert d["occupancy"] == occ
-    assert BCPlan.from_json(d).occupancy == occ
-    # dense plans (and old JSON records without the field) stay None
-    pl_dense = plan(g, BCQuery(mode="exact"), n_devices=1)
-    assert pl_dense.occupancy is None
-    old = pl_dense.to_json()
-    old.pop("occupancy", None)
-    assert BCPlan.from_json(old).occupancy is None
+    assert "occupancy" not in d
+    assert BCPlan.from_json(d) == pl
+    # records that still carry the key load, and drop it
+    assert BCPlan.from_json(dict(d, occupancy=occ)) == pl
+    # dense solves keep no occupancy
+    dense = solve(g, BCQuery(mode="exact", n_b=16,
+                             execution=ExecutionConfig(backend="dense")))
+    assert dense.occupancy is None
 
 
 def test_dense_relax_cp_transpose_is_hoisted(small_graph):

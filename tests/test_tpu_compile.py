@@ -114,3 +114,27 @@ def test_coo_moments_step_compiles_for_v5e(one_chip, no_persistent_cache):
         adj, _shape(one_chip, nb, dtype=jnp.int32),
         _shape(one_chip, nb, dtype=jnp.bool_)).compile()
     assert _fits(compiled)
+
+
+def test_csr_traced_step_compiles_for_v5e_with_scopes(one_chip,
+                                                      no_persistent_cache):
+    """The exact cell's program (CSR ladder, both traces returned) at a
+    small size: it compiles for v5e, and the TPU compiler keeps the named
+    scopes in the op metadata a profiler trace reports as ``tf_op``."""
+    from repro.core.adjacency import csr_adj_from_graph
+    from repro.core.mfbc import mfbc_batch_moments_traced
+    from repro.graphs.generators import rmat
+
+    g, _ = rmat(8, 16, seed=0).remove_isolated()
+    adj = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, *x.shape, dtype=x.dtype),
+        csr_adj_from_graph(g, n_b=16))
+    nb = 16
+    compiled = mfbc_batch_moments_traced.lower(
+        adj, _shape(one_chip, nb, dtype=jnp.int32),
+        _shape(one_chip, nb, dtype=jnp.bool_)).compile()
+    assert _fits(compiled)
+    text = compiled.as_text()
+    for scope in ("mfbf/", "mfbr/", "/init/", "/relax.pick/", "/relax.rung0/",
+                  "/relax.full_edge/", "/update/", "batch.reduce/"):
+        assert scope in text, scope
