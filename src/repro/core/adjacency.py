@@ -169,8 +169,11 @@ class CsrAdj:
 
     The same arcs are carried twice: sorted by src with row pointers
     (``indptr``/``src``/``dst``/``w`` — the by-src arrays double as valid
-    COO for the overflow fallback) and sorted by dst (``indptr_in``/
-    ``src_in``/``w_in`` — the CSC side MFBr's backward action expands).
+    COO for the overflow fallback) and sorted by dst (``indptr_in`` —
+    the CSC side MFBr's backward action expands). What a compacted relax
+    reads at an arc id, the arc's other end and its weight, sits in one
+    ``monoids.arc_table`` per side (``arcs``, ``arcs_in``), built once
+    here so that each relax reads it with one gather.
     ``caps`` is the static power-of-two capacity ladder ``((vcap, ecap),
     ...)``: each relax counts the *union-column* frontier (vertices
     active in any batch row) and its incident arcs, picks the smallest
@@ -183,15 +186,15 @@ class CsrAdj:
     src: jax.Array  # (E,) int32, sorted ascending
     dst: jax.Array  # (E,) int32
     w: jax.Array  # (E,) float32, padding = inf
-    indptr_in: jax.Array  # (n+1,) int32 row pointers into the by-dst arrays
-    src_in: jax.Array  # (E,) int32 — predecessor of each in-arc
-    w_in: jax.Array  # (E,) float32
+    arcs: jax.Array  # (E, 2) int32 by-src arc_table: dst, w
+    indptr_in: jax.Array  # (n+1,) int32 row pointers into ``arcs_in``
+    arcs_in: jax.Array  # (E, 2) int32 by-dst arc_table: src, w
     n_static: int
     caps: Tuple[Tuple[int, int], ...]
 
     def tree_flatten(self):
-        return ((self.indptr, self.src, self.dst, self.w,
-                 self.indptr_in, self.src_in, self.w_in),
+        return ((self.indptr, self.src, self.dst, self.w, self.arcs,
+                 self.indptr_in, self.arcs_in),
                 (self.n_static, self.caps))
 
     @classmethod
@@ -242,7 +245,7 @@ class CsrAdj:
             F, self.indptr,
             lambda v, e: functools.partial(
                 monoids.multpath_relax_csr, indptr=self.indptr,
-                dst=self.dst, w=self.w, n=self.n, vcap=v, ecap=e),
+                arcs=self.arcs, n=self.n, vcap=v, ecap=e),
             lambda Fb: monoids.multpath_relax_coo(
                 Fb, self.src, self.dst, self.w, self.n))
 
@@ -251,8 +254,7 @@ class CsrAdj:
             F, self.indptr_in,
             lambda v, e: functools.partial(
                 monoids.centpath_relax_csr, indptr_in=self.indptr_in,
-                src_in=self.src_in, w_in=self.w_in, n=self.n, vcap=v,
-                ecap=e),
+                arcs_in=self.arcs_in, n=self.n, vcap=v, ecap=e),
             lambda Fb: monoids.centpath_relax_coo(
                 Fb, self.src, self.dst, self.w, self.n))
 
@@ -328,5 +330,7 @@ def csr_adj_from_graph(g: Graph, *, n_b: int = 64,
         caps = frontier_caps(n_b, g.n, int(src_s.shape[0]))
     return CsrAdj(jnp.asarray(indptr), jnp.asarray(src_s),
                   jnp.asarray(dst_s), jnp.asarray(w_s),
-                  jnp.asarray(indptr_in), jnp.asarray(src_in),
-                  jnp.asarray(w_in), g.n, tuple(caps))
+                  jnp.asarray(monoids.arc_table(dst_s, w_s)),
+                  jnp.asarray(indptr_in),
+                  jnp.asarray(monoids.arc_table(src_in, w_in)), g.n,
+                  tuple(caps))

@@ -37,6 +37,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INF = jnp.inf
 
@@ -214,6 +215,56 @@ def count_sp_children_dense(Tw: jax.Array, A: jax.Array, *, block: int = 256
 # ---------------------------------------------------------------------------
 # COO (sparse) regime: segment-op relaxations.
 # ---------------------------------------------------------------------------
+#
+# Each indexed pass over the arcs costs about the same per index whatever
+# the width of the row it moves (on TPU v5e an (E, 32) segment_sum takes
+# what an (E, 16) one does), so every relax below makes one gather per
+# distinct index vector (fields read at the same index are stacked into
+# one table first) and one scatter per reducer (the p and c tie sums share
+# a window). The candidates and reductions are those of the per-field
+# formulation, so results are bitwise the same.
+
+
+def _gather_cols(rows, idx: jax.Array):
+    """``[r[:, idx] for r in rows]`` with one gather: the (nb, n) fields
+    are stacked into a (k·nb, n) table, gathered once, then split."""
+    nb = rows[0].shape[0]
+    got = jnp.concatenate(rows, axis=0)[:, idx]
+    return [got[i * nb:(i + 1) * nb] for i in range(len(rows))]
+
+
+def _multpath_scatter(Fw: jax.Array, Fm: jax.Array, wa: jax.Array,
+                      seg: jax.Array, n: int) -> Multpath:
+    """Reduce the (nb, E) gathered frontier into ``seg``'s segments:
+    min of ``Fw + wa``, then the tie-masked multiplicity sum."""
+    cand = Fw + wa[None, :]  # (nb, E); inf + x = inf
+    minw = jax.ops.segment_min(cand.T, seg, num_segments=n).T  # (nb, n)
+    tie = (cand == minw[:, seg]) & jnp.isfinite(cand)
+    m = jax.ops.segment_sum(jnp.where(tie, Fm, 0.0).T, seg,
+                            num_segments=n).T
+    # segment_min of empty segments yields +inf-ish max value for floats;
+    # normalize: entries with zero multiplicity are inactive.
+    minw = jnp.where(m > 0, minw, INF)
+    return Multpath(minw, m)
+
+
+def _centpath_scatter(Fw: jax.Array, Fp: jax.Array, wa: jax.Array,
+                      alive: jax.Array, seg: jax.Array, n: int) -> Centpath:
+    """Reduce the (nb, E) gathered frontier into ``seg``'s segments:
+    max of ``Fw - wa`` over live arcs, then the p and c tie sums in one
+    scatter (c's update is the tie mask: every contributing child
+    counts once)."""
+    cand = jnp.where(alive[None, :] & jnp.isfinite(Fw), Fw - wa[None, :],
+                     -INF)  # (nb, E)
+    maxw = jax.ops.segment_max(cand.T, seg, num_segments=n).T  # (nb, n)
+    tie = (cand == maxw[:, seg]) & jnp.isfinite(cand)
+    nb = Fw.shape[0]
+    pc = jax.ops.segment_sum(
+        jnp.concatenate([jnp.where(tie, Fp, 0.0), jnp.where(tie, 1.0, 0.0)]).T,
+        seg, num_segments=n).T  # (2·nb, n)
+    p, c = pc[:nb], pc[nb:]
+    maxw = jnp.where(c > 0, maxw, -INF)
+    return Centpath(maxw, p, c)
 
 
 def multpath_relax_coo(F: Multpath, src: jax.Array, dst: jax.Array,
@@ -223,16 +274,8 @@ def multpath_relax_coo(F: Multpath, src: jax.Array, dst: jax.Array,
     src/dst/w: (E,) padded COO arcs (padding arcs carry w = inf).
     F.w/F.m: (nb, n). Cost O(nb * E); chunk over nb upstream if needed.
     """
-    cand = F.w[:, src] + w[None, :]  # (nb, E)
-    minw = jax.ops.segment_min(cand.T, dst, num_segments=n,
-                               indices_are_sorted=False).T  # (nb, n)
-    tie = (cand == minw[:, dst]) & jnp.isfinite(cand)
-    contrib = jnp.where(tie, F.m[:, src], 0.0)
-    m = jax.ops.segment_sum(contrib.T, dst, num_segments=n).T
-    # segment_min of empty segments yields +inf-ish max value for floats;
-    # normalize: entries with zero multiplicity are inactive.
-    minw = jnp.where(m > 0, minw, INF)
-    return Multpath(minw, m)
+    Fw, Fm = _gather_cols([F.w, F.m], src)
+    return _multpath_scatter(Fw, Fm, w, dst, n)
 
 
 def centpath_relax_coo(F: Centpath, src: jax.Array, dst: jax.Array,
@@ -242,16 +285,8 @@ def centpath_relax_coo(F: Centpath, src: jax.Array, dst: jax.Array,
     For arc (v -> u, a): cand(s, v) over children u: F.w(s, u) - a.
     Segment over ``src`` (the predecessor side).
     """
-    cand = F.w[:, dst] - w[None, :]  # (nb, E)
-    active = jnp.isfinite(F.w[:, dst]) & jnp.isfinite(w)[None, :]
-    cand = jnp.where(active, cand, -INF)
-    maxw = jax.ops.segment_max(cand.T, src, num_segments=n).T  # (nb, n)
-    tie = (cand == maxw[:, src]) & jnp.isfinite(cand)
-    p = jax.ops.segment_sum(jnp.where(tie, F.p[:, dst], 0.0).T, src,
-                            num_segments=n).T
-    c = jax.ops.segment_sum(jnp.where(tie, 1.0, 0.0).T, src, num_segments=n).T
-    maxw = jnp.where(c > 0, maxw, -INF)
-    return Centpath(maxw, p, c)
+    Fw, Fp = _gather_cols([F.w, F.p], dst)
+    return _centpath_scatter(Fw, Fp, w, jnp.isfinite(w), src, n)
 
 
 def count_sp_children_coo(Tw: jax.Array, src: jax.Array, dst: jax.Array,
@@ -276,27 +311,30 @@ def _compact_cols(mask: jax.Array, indptr: jax.Array, vcap: int):
     instead of (row, vertex) pairs keeps the batch axis contiguous, so
     the relax below runs the same SIMD-friendly 2D segment ops as the
     COO kernels, just over the frontier's incident arc set. Returns
-    (u, offs): per-slot vertex id and the inclusive cumsum of per-slot
-    arc degrees (``offs[-1]`` = total incident arcs). Slots past the
-    population carry degree 0, so they own no arc range.
+    (u, first, offs): per-slot vertex id, its first arc id, and the
+    inclusive cumsum of per-slot arc degrees (``offs[-1]`` = total
+    incident arcs). Slots past the population carry degree 0, so they
+    own no arc range.
     """
     n = mask.shape[1]
     cols = jnp.nonzero(jnp.any(mask, axis=0), size=vcap, fill_value=n)[0]
     valid = cols < n
     u = jnp.where(valid, cols, 0).astype(jnp.int32)
-    deg = jnp.where(valid, indptr[u + 1] - indptr[u], 0)
+    first = indptr[u]
+    deg = jnp.where(valid, indptr[u + 1] - first, 0)
     offs = jnp.cumsum(deg)
-    return u, offs
+    return u, first, offs
 
 
-def _expand_edges(u: jax.Array, offs: jax.Array, indptr: jax.Array,
+def _expand_edges(u: jax.Array, first: jax.Array, offs: jax.Array,
                   ecap: int):
     """Expand compacted slots into ``ecap`` load-balanced arc slots.
 
     Owner assignment is a scatter of each populated slot's start offset
     followed by a cumulative max — two linear passes over ``ecap``, no
-    per-arc binary search. Returns (owner, arc_id, live); dead slots
-    (``pos >= offs[-1]``) are masked.
+    per-arc binary search. The owner's vertex and arc base come from one
+    gather of a per-slot ``[u, first - start]`` table. Returns (uj, eid,
+    live); dead slots (``pos >= offs[-1]``) are masked.
     """
     vcap = u.shape[0]
     pos = jnp.arange(ecap, dtype=offs.dtype)
@@ -308,67 +346,68 @@ def _expand_edges(u: jax.Array, offs: jax.Array, indptr: jax.Array,
     owner = jnp.zeros((ecap,), jnp.int32).at[tgt].max(slots, mode="drop")
     j = jax.lax.cummax(owner)
     live = pos < offs[-1]
-    eid = jnp.where(live, indptr[u[j]] + (pos - starts[j]), 0)
-    return j, eid.astype(jnp.int32), live
+    tab = jnp.stack([u, first - starts], axis=1)[j]  # (ecap, 2)
+    eid = jnp.where(live, pos + tab[:, 1], 0)
+    return tab[:, 0], eid.astype(jnp.int32), live
 
 
-def multpath_relax_csr(F: Multpath, indptr: jax.Array, dst: jax.Array,
-                       w: jax.Array, n: int, *, vcap: int, ecap: int
-                       ) -> Multpath:
+def arc_table(other: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """(E, 2) int32 ``[other end, bit pattern of w]`` of CSR-sorted arcs,
+    built once on the host: what a compacted relax reads at an arc id,
+    in one gather (``_expand_arcs``)."""
+    return np.stack([np.asarray(other, np.int32),
+                     np.asarray(w, np.float32).view(np.int32)], axis=1)
+
+
+def _expand_arcs(active: jax.Array, indptr: jax.Array, arcs: jax.Array,
+                 vcap: int, ecap: int):
+    """Arc slots of the union frontier's incident CSR ranges: (uj, other,
+    wa, live) — each slot's frontier vertex, the arc's other end and
+    weight (one gather of the ``arc_table``), and whether it is live."""
+    u, first, offs = _compact_cols(active, indptr, vcap)
+    uj, eid, live = _expand_edges(u, first, offs, ecap)
+    arc = arcs[eid]  # (ecap, 2)
+    wa = jax.lax.bitcast_convert_type(arc[:, 1], jnp.float32)
+    return uj, arc[:, 0], wa, live
+
+
+def multpath_relax_csr(F: Multpath, indptr: jax.Array, arcs: jax.Array,
+                       n: int, *, vcap: int, ecap: int) -> Multpath:
     """Frontier-compacted ``multpath_relax_coo`` over by-src CSR arcs.
 
-    Only arcs leaving the union frontier are touched: active columns
-    compact into ``vcap`` slots, their out-arc ranges into ``ecap`` arc
-    slots, and (nb, ecap) candidates scatter with the same batched 2D
-    segment ops as the COO kernel. Dead arc slots carry w = inf — the
-    COO kernel's own padding idiom — so they are monoid-inert. The
-    result is exactly ``multpath_relax_coo`` *provided* the frontier
-    fits — active columns ``<= vcap`` and incident arcs ``<= ecap`` —
-    which the caller guarantees by capacity-bucket selection
-    (``CsrAdj``): arcs from inactive columns hold F.w = inf in every
-    batch row and can never win a segment min.
+    ``arcs`` is the by-src ``arc_table`` (dst, w). Only arcs leaving the
+    union frontier are touched: active columns compact into ``vcap``
+    slots, their out-arc ranges into ``ecap`` arc slots, and (nb, ecap)
+    candidates scatter with the same batched 2D segment ops as the COO
+    kernel. Dead arc slots carry w = inf — the COO kernel's own padding
+    idiom — so they are monoid-inert. The result is exactly
+    ``multpath_relax_coo`` *provided* the frontier fits — active columns
+    ``<= vcap`` and incident arcs ``<= ecap`` — which the caller
+    guarantees by capacity-bucket selection (``CsrAdj``): arcs from
+    inactive columns hold F.w = inf in every batch row and can never win
+    a segment min.
     """
-    mask = jnp.isfinite(F.w)
-    u, offs = _compact_cols(mask, indptr, vcap)
-    j, eid, live = _expand_edges(u, offs, indptr, ecap)
-    uj = u[j]
-    wa = jnp.where(live, w[eid], INF)
-    seg = jnp.where(live, dst[eid], 0)
-    cand = F.w[:, uj] + wa[None, :]  # (nb, ecap)
-    minw = jax.ops.segment_min(cand.T, seg, num_segments=n).T
-    tie = (cand == minw[:, seg]) & jnp.isfinite(cand)
-    m = jax.ops.segment_sum(jnp.where(tie, F.m[:, uj], 0.0).T, seg,
-                            num_segments=n).T
-    minw = jnp.where(m > 0, minw, INF)
-    return Multpath(minw, m)
+    uj, dst, wa, live = _expand_arcs(jnp.isfinite(F.w), indptr, arcs,
+                                     vcap, ecap)
+    Fw, Fm = _gather_cols([F.w, F.m], uj)
+    return _multpath_scatter(Fw, Fm, jnp.where(live, wa, INF),
+                             jnp.where(live, dst, 0), n)
 
 
-def centpath_relax_csr(F: Centpath, indptr_in: jax.Array, src_in: jax.Array,
-                       w_in: jax.Array, n: int, *, vcap: int, ecap: int
+def centpath_relax_csr(F: Centpath, indptr_in: jax.Array,
+                       arcs_in: jax.Array, n: int, *, vcap: int, ecap: int
                        ) -> Centpath:
     """Frontier-compacted ``centpath_relax_coo`` over by-dst (CSC) arcs.
 
-    The active side of the Brandes action is the *child* (the arc's
-    dst): active child columns compact into slots, each child's in-arc
-    range expands, and (nb, ecap) candidates scatter to the predecessor
-    side with the batched 2D segment ops of the COO kernel. Equals
+    ``arcs_in`` is the by-dst ``arc_table`` (src, w). The active side of
+    the Brandes action is the *child* (the arc's dst): active child
+    columns compact into slots, each child's in-arc range expands, and
+    (nb, ecap) candidates scatter to the predecessor side with the
+    batched 2D segment ops of the COO kernel. Equals
     ``centpath_relax_coo`` under the same capacity proviso.
     """
-    mask = jnp.isfinite(F.w)
-    u, offs = _compact_cols(mask, indptr_in, vcap)
-    j, eid, live = _expand_edges(u, offs, indptr_in, ecap)
-    uj = u[j]
-    wa = w_in[eid]
+    uj, src, wa, live = _expand_arcs(jnp.isfinite(F.w), indptr_in,
+                                     arcs_in, vcap, ecap)
     alive = live & jnp.isfinite(wa)  # padding arcs never contribute
-    seg = jnp.where(alive, src_in[eid], 0)
-    Fw = F.w[:, uj]
-    cand = jnp.where(alive[None, :] & jnp.isfinite(Fw),
-                     Fw - wa[None, :], -INF)  # (nb, ecap)
-    maxw = jax.ops.segment_max(cand.T, seg, num_segments=n).T
-    tie = (cand == maxw[:, seg]) & jnp.isfinite(cand)
-    p = jax.ops.segment_sum(jnp.where(tie, F.p[:, uj], 0.0).T, seg,
-                            num_segments=n).T
-    c = jax.ops.segment_sum(jnp.where(tie, 1.0, 0.0).T, seg,
-                            num_segments=n).T
-    maxw = jnp.where(c > 0, maxw, -INF)
-    return Centpath(maxw, p, c)
+    Fw, Fp = _gather_cols([F.w, F.p], uj)
+    return _centpath_scatter(Fw, Fp, wa, alive, jnp.where(alive, src, 0), n)
