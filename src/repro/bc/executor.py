@@ -366,8 +366,9 @@ class SingleHostExecutor(_ExecutorBase):
         running summary, in Python ints."""
         def rows(tr):
             k = min(int(tr.iters), TRACE_CAP)
-            return [[int(f), int(b), int(a)] for f, b, a in
-                    zip(tr.fnnz[:k], tr.bucket[:k], tr.arcs[:k])]
+            return [[int(f), int(b), int(a), int(e)] for f, b, a, e in
+                    zip(tr.fnnz[:k], tr.bucket[:k], tr.arcs[:k],
+                        tr.entry_arcs[:k])]
         rows_bf, rows_br = rows(tr_bf), rows(tr_br)
         o = self._occ
         o["batches"] = o.get("batches", 0) + 1
@@ -381,6 +382,13 @@ class SingleHostExecutor(_ExecutorBase):
                     "arc_slots"):
             o[key] = (o.get(key, 0) + int(getattr(tr_bf, key))
                       + int(getattr(tr_br, key)))
+        o["entry_arcs"] = (o.get("entry_arcs", 0)
+                           + sum(r[3] for r in rows_bf + rows_br))
+        for sweep, r, tr in (("bf", rows_bf, tr_bf), ("br", rows_br, tr_br)):
+            o[f"entries_{sweep}"] = (o.get(f"entries_{sweep}", 0)
+                                     + sum(x[0] for x in r))
+            o[f"reached_{sweep}"] = (o.get(f"reached_{sweep}", 0)
+                                     + int(tr.reached))
         o["relax_calls"] = (o.get("relax_calls", 0) + int(tr_bf.iters)
                             + int(tr_br.iters))
         calls = max(o["relax_calls"], 1)
@@ -392,13 +400,20 @@ class SingleHostExecutor(_ExecutorBase):
         From the most recent batch: the per-iteration profiles of the
         forward (``_bf``) and backward (``_br``) sweeps —
         ``per_iter_*`` the frontier nnz, ``rows_*`` the ``[fnnz, rung,
-        arcs]`` of each relax (rung ``len(caps)`` is the full-edge-list
-        fallback). Accumulated over every traced batch this executor
-        ran: ``batches``, ``relax_calls``, ``overflows``,
+        arcs, entry_arcs]`` of each relax (rung ``len(caps)`` is the
+        full-edge-list fallback). Accumulated over every traced batch
+        this executor ran: ``batches``, ``relax_calls``, ``overflows``,
         ``compact_hits``, ``hit_rate``, ``frontier_arcs`` (arcs leaving
-        the union frontier) and ``arc_slots`` (arc slots the chosen
+        the union frontier), ``arc_slots`` (arc slots the chosen
         branches processed; ``frontier_arcs / arc_slots`` is the relax's
-        useful share of its work).
+        useful share of its work), ``entry_arcs`` (the degrees of the
+        active (row, vertex) entries: what an entry-level relax would
+        touch), and per sweep ``entries_*`` (frontier entries over every
+        relax) and ``reached_*`` (finite entries of T at the sweep's
+        end; ``entries_bf / reached_bf`` is MFBF's re-entry, 1 on unit
+        weights). Per-iteration counts come from the trace's
+        ``TRACE_CAP`` slots, so a sweep longer than that adds only the
+        iterations its slots hold.
         """
         return dict(self._occ) if self._occ else None
 

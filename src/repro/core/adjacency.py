@@ -31,6 +31,11 @@ class RelaxStats(NamedTuple):
     compaction at all); ``overflow`` is 1 iff the fallback ran. ``arcs``
     is the useful work (arcs leaving the union frontier), ``slots`` the
     work done (the chosen rung's ``ecap``, or every arc on the fallback).
+    ``entry_arcs`` sums, over the active (row, vertex) entries, the
+    vertex's degree in the relax's direction: the arcs a relax that
+    expanded entries instead of union columns would touch. It is int32,
+    exact while ``n_b · E < 2**31``; past that it wraps (the count is
+    then wrong, the relax is not).
     """
 
     nnz: jax.Array  # int32 — active frontier entries seen by this relax
@@ -38,12 +43,13 @@ class RelaxStats(NamedTuple):
     bucket: jax.Array  # int32 — ladder index chosen
     overflow: jax.Array  # int32 — 1 iff the full-edge-list fallback ran
     slots: jax.Array  # int32 — arc slots the chosen branch processed
+    entry_arcs: jax.Array  # int32 — Σ degree over active (row, vertex)
 
 
 def no_compaction() -> RelaxStats:
     """Stats of a relax with no capacity ladder (dense, COO)."""
     zero = jnp.int32(0)
-    return RelaxStats(zero, zero, jnp.int32(-1), zero, zero)
+    return RelaxStats(zero, zero, jnp.int32(-1), zero, zero, zero)
 
 
 def _scoped(name: str, fn):
@@ -215,11 +221,15 @@ class CsrAdj:
         finite) and choose the smallest fitting bucket. ``nnz`` is active
         *columns* (vertices live in any batch row — what the compacting
         relaxes expand), ``arcs`` their incident arc total, ``slots`` the
-        arc slots the chosen branch processes."""
+        arc slots the chosen branch processes, ``entry_arcs`` the arcs of
+        every active (row, vertex) entry (from the same per-column count
+        that marks the union columns)."""
         deg = indptr[1:] - indptr[:-1]
-        colmask = jnp.any(jnp.isfinite(Fw), axis=0)
+        per_col = jnp.sum(jnp.isfinite(Fw), axis=0, dtype=jnp.int32)
+        colmask = per_col > 0
         nnz = jnp.sum(colmask.astype(jnp.int32))
         arcs = jnp.sum(jnp.where(colmask, deg, 0)).astype(jnp.int32)
+        entry_arcs = jnp.sum(per_col * deg, dtype=jnp.int32)
         bucket = jnp.int32(len(self.caps))
         for i in reversed(range(len(self.caps))):
             vcap, ecap = self.caps[i]
@@ -227,18 +237,19 @@ class CsrAdj:
             bucket = jnp.where(fits, jnp.int32(i), bucket)
         slots = jnp.asarray([e for _, e in self.caps] + [self.src.shape[0]],
                             jnp.int32)[bucket]
-        return nnz, arcs, bucket, slots
+        return nnz, arcs, bucket, slots, entry_arcs
 
     def _switch(self, F, indptr, rung, full_edge):
         """Run ``F`` through the smallest fitting rung (``rung(vcap,
         ecap)``) or, past the ladder, ``full_edge``; with its stats."""
-        nnz, arcs, bucket, slots = self._pick_bucket(F.w, indptr)
+        nnz, arcs, bucket, slots, entry_arcs = self._pick_bucket(F.w, indptr)
         branches = [_scoped(f"relax.rung{i}", rung(v, e))
                     for i, (v, e) in enumerate(self.caps)]
         branches.append(_scoped("relax.full_edge", full_edge))
         out = jax.lax.switch(bucket, branches, F)
         overflow = (bucket == len(self.caps)).astype(jnp.int32)
-        return out, RelaxStats(nnz, arcs, bucket, overflow, slots)
+        return out, RelaxStats(nnz, arcs, bucket, overflow, slots,
+                               entry_arcs)
 
     def relax_mp_stats(self, F: Multpath) -> Tuple[Multpath, RelaxStats]:
         return self._switch(

@@ -29,9 +29,14 @@ bitwise-unchanged.
 Every sweep threads a :class:`SweepTrace` through its one loop body;
 ``trace=True`` only returns it. Per iteration it records the frontier
 nnz and, for adjacencies with frontier compaction (``CsrAdj``), the rung
-that served the relax and the arcs leaving the union frontier; per sweep
-the relax calls a rung served, those that overflowed to the full edge
-list, and the arcs needed against the arc slots processed.
+that served the relax, the arcs leaving the union frontier and the arcs
+of the active entries themselves; per sweep the relax calls a rung
+served, those that overflowed to the full edge list, the arcs needed
+against the arc slots processed, and the entries of ``T`` reached. On a
+weighted graph an entry can join the frontier again each time a path of
+more edges lowers its distance, so the frontier nnz summed over a sweep
+over its ``reached`` is the sweep's label-correcting re-entry (1 on
+unit weights).
 
 Each stage runs under a ``jax.named_scope`` (``mfbf`` → ``init``, the
 relax scopes of ``repro.core.adjacency``, ``update``), so a profiler
@@ -58,7 +63,10 @@ class SweepTrace(NamedTuple):
 
     Per-iteration slots hold -1 where unused; ``bucket`` is -1 on an
     adjacency with no capacity ladder, ``len(caps)`` on the full-edge-list
-    fallback. ``frontier_arcs`` and ``arc_slots`` are 0 without a ladder.
+    fallback. ``frontier_arcs``, ``arc_slots`` and ``entry_arcs`` are 0
+    without a ladder. ``entry_arcs`` stays per iteration so that each
+    int32 holds at most ``n_b · E`` (see ``RelaxStats``); the host sums
+    it in Python ints. ``reached`` is set once, after the loop.
     """
 
     fnnz: jax.Array  # (TRACE_CAP,) int32 frontier nnz entering each relax
@@ -69,6 +77,8 @@ class SweepTrace(NamedTuple):
     compact_hits: jax.Array  # int32 — relax calls served by a capacity bucket
     frontier_arcs: jax.Array  # int32 — Σ arcs over every iteration
     arc_slots: jax.Array  # int32 — Σ arc slots the chosen branches processed
+    entry_arcs: jax.Array  # (TRACE_CAP,) int32 arcs of the active entries
+    reached: jax.Array  # int32 — finite entries of T when the sweep ends
 
     def record(self, nact: jax.Array, st: RelaxStats) -> "SweepTrace":
         """The trace after one more relax, which saw ``nact`` entries."""
@@ -80,13 +90,21 @@ class SweepTrace(NamedTuple):
                           self.overflows + st.overflow,
                           self.compact_hits + hit,
                           self.frontier_arcs + st.arcs,
-                          self.arc_slots + st.slots)
+                          self.arc_slots + st.slots,
+                          self.entry_arcs.at[slot].set(st.entry_arcs),
+                          self.reached)
 
 
 def empty_trace() -> SweepTrace:
     unused = jnp.full((TRACE_CAP,), -1, jnp.int32)
     zero = jnp.int32(0)
-    return SweepTrace(unused, unused, unused, zero, zero, zero, zero, zero)
+    return SweepTrace(unused, unused, unused, zero, zero, zero, zero, zero,
+                      unused, zero)
+
+
+def count_finite(Tw: jax.Array) -> jax.Array:
+    """int32 count of the finite entries of ``Tw`` (a sweep's reach)."""
+    return jnp.sum(jnp.isfinite(Tw), dtype=jnp.int32)
 
 
 def _frontier_active(F: Multpath) -> jax.Array:
@@ -158,5 +176,6 @@ def mfbf(adj, sources: jax.Array, *,
         else:
             state = jax.lax.fori_loop(0, bound, lambda _, s: _step(adj, s),
                                       state)
-    T, _, _, tr = state
+        T, _, _, tr = state
+        tr = tr._replace(reached=count_finite(T.w))
     return (T.w, T.m, tr) if trace else (T.w, T.m)

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.adjacency import RelaxStats, no_compaction
-from repro.core.mfbf import SweepTrace, empty_trace
+from repro.core.mfbf import SweepTrace, count_finite, empty_trace
 from repro.core.monoids import INF, Centpath
 
 
@@ -101,5 +101,6 @@ def mfbr(adj, Tw: jax.Array, Tm: jax.Array, *,
                 lambda st: (st[4] > 0) & (st[5].iters < bound), body, state)
         else:
             state = jax.lax.fori_loop(0, bound, lambda _, st: body(st), state)
-    Zp, tr = state[0], state[5]
+        # the entries the sweep retires: every finite entry of T
+        Zp, tr = state[0], state[5]._replace(reached=count_finite(Tw))
     return (Zp, tr) if trace else Zp
