@@ -174,12 +174,16 @@ class CsrAdj:
     """Dual-sorted arc lists with frontier-compacted relaxations.
 
     The same arcs are carried twice: sorted by src with row pointers
-    (``indptr``/``src``/``dst``/``w`` — the by-src arrays double as valid
-    COO for the overflow fallback) and sorted by dst (``indptr_in`` —
-    the CSC side MFBr's backward action expands). What a compacted relax
-    reads at an arc id, the arc's other end and its weight, sits in one
-    ``monoids.arc_table`` per side (``arcs``, ``arcs_in``), built once
-    here so that each relax reads it with one gather.
+    (``indptr``/``src``/``dst``/``w``) and sorted by dst (``indptr_in``,
+    ``dst_in`` — the CSC side MFBr's backward action expands). What a
+    compacted relax reads at an arc id, the arc's other end and its
+    weight, sits in one ``monoids.arc_table`` per side (``arcs``,
+    ``arcs_in``), built once here so that each relax reads it with one
+    gather. The full-edge fallback runs each relax over the arcs sorted
+    by its segment id, so that every full-arc scatter is told its ids
+    are sorted: MFBF (segments by dst) over the by-dst arcs, MFBr and the
+    SP-child count (by src) over the by-src arrays. ``check_sorted``
+    holds the two orders when the container is built.
     ``caps`` is the static power-of-two capacity ladder ``((vcap, ecap),
     ...)``: each relax counts the *union-column* frontier (vertices
     active in any batch row) and its incident arcs, picks the smallest
@@ -195,12 +199,13 @@ class CsrAdj:
     arcs: jax.Array  # (E, 2) int32 by-src arc_table: dst, w
     indptr_in: jax.Array  # (n+1,) int32 row pointers into ``arcs_in``
     arcs_in: jax.Array  # (E, 2) int32 by-dst arc_table: src, w
+    dst_in: jax.Array  # (E,) int32 by-dst arc heads, sorted ascending
     n_static: int
     caps: Tuple[Tuple[int, int], ...]
 
     def tree_flatten(self):
         return ((self.indptr, self.src, self.dst, self.w, self.arcs,
-                 self.indptr_in, self.arcs_in),
+                 self.indptr_in, self.arcs_in, self.dst_in),
                 (self.n_static, self.caps))
 
     @classmethod
@@ -210,6 +215,16 @@ class CsrAdj:
     @property
     def n(self) -> int:
         return self.n_static
+
+    def check_sorted(self) -> None:
+        """Raise unless ``src`` and ``dst_in`` are non-decreasing. The
+        full-edge relaxes and ``count_sp_children`` tell XLA that these
+        segment ids are sorted, and a false claim gives wrong answers
+        without an error."""
+        for name in ("src", "dst_in"):
+            ids = np.asarray(getattr(self, name))
+            if np.any(ids[1:] < ids[:-1]):
+                raise ValueError(f"CsrAdj.{name} is not sorted ascending")
 
     def gather_rows(self, sources: jax.Array) -> jax.Array:
         return _gather_rows_scatter(self.src, self.dst, self.w, self.n,
@@ -251,14 +266,23 @@ class CsrAdj:
         return out, RelaxStats(nnz, arcs, bucket, overflow, slots,
                                entry_arcs)
 
+    def _full_edge_mp(self, F: Multpath) -> Multpath:
+        """MFBF over every arc in by-dst order: its segment ids
+        (``dst_in``) are sorted. Within a segment the arcs keep the
+        by-src order, so the sums add what the by-src relax adds, in the
+        same order."""
+        src_in = self.arcs_in[:, 0]
+        w_in = jax.lax.bitcast_convert_type(self.arcs_in[:, 1], jnp.float32)
+        return monoids.multpath_relax_coo(F, src_in, self.dst_in, w_in,
+                                          self.n, sorted_seg=True)
+
     def relax_mp_stats(self, F: Multpath) -> Tuple[Multpath, RelaxStats]:
         return self._switch(
             F, self.indptr,
             lambda v, e: functools.partial(
                 monoids.multpath_relax_csr, indptr=self.indptr,
                 arcs=self.arcs, n=self.n, vcap=v, ecap=e),
-            lambda Fb: monoids.multpath_relax_coo(
-                Fb, self.src, self.dst, self.w, self.n))
+            self._full_edge_mp)
 
     def relax_cp_stats(self, F: Centpath) -> Tuple[Centpath, RelaxStats]:
         return self._switch(
@@ -267,7 +291,7 @@ class CsrAdj:
                 monoids.centpath_relax_csr, indptr_in=self.indptr_in,
                 arcs_in=self.arcs_in, n=self.n, vcap=v, ecap=e),
             lambda Fb: monoids.centpath_relax_coo(
-                Fb, self.src, self.dst, self.w, self.n))
+                Fb, self.src, self.dst, self.w, self.n, sorted_seg=True))
 
     def relax_mp(self, F: Multpath) -> Multpath:
         return self.relax_mp_stats(F)[0]
@@ -277,7 +301,7 @@ class CsrAdj:
 
     def count_sp_children(self, Tw: jax.Array) -> jax.Array:
         return monoids.count_sp_children_coo(Tw, self.src, self.dst, self.w,
-                                             self.n)
+                                             self.n, sorted_seg=True)
 
 
 def frontier_caps(n_b: int, n: int, m: int) -> Tuple[Tuple[int, int], ...]:
@@ -322,6 +346,8 @@ def csr_adj_from_graph(g: Graph, *, n_b: int = 64,
                        pad_multiple: int = 1) -> CsrAdj:
     """Build the dual-sorted container on the host (stable sorts).
 
+    The by-dst order is the by-src order stably sorted by dst, so within
+    each dst the arcs keep their by-src order.
     ``n_b`` sizes the default capacity ladder (it bounds the batch axis
     of the frontiers the relaxes will see); pass explicit ``caps`` to
     override — tests force escalation with caps like ``((1, 1),)``.
@@ -332,16 +358,15 @@ def csr_adj_from_graph(g: Graph, *, n_b: int = 64,
     indptr = np.zeros(g.n + 1, np.int32)
     np.add.at(indptr, src_s + 1, 1)
     np.cumsum(indptr, out=indptr)
-    order_in = np.argsort(dst, kind="stable")
-    src_in, dst_in, w_in = src[order_in], dst[order_in], w[order_in]
+    order_in = np.argsort(dst_s, kind="stable")
+    src_in, dst_in, w_in = src_s[order_in], dst_s[order_in], w_s[order_in]
     indptr_in = np.zeros(g.n + 1, np.int32)
     np.add.at(indptr_in, dst_in + 1, 1)
     np.cumsum(indptr_in, out=indptr_in)
     if caps is None:
         caps = frontier_caps(n_b, g.n, int(src_s.shape[0]))
-    return CsrAdj(jnp.asarray(indptr), jnp.asarray(src_s),
-                  jnp.asarray(dst_s), jnp.asarray(w_s),
-                  jnp.asarray(monoids.arc_table(dst_s, w_s)),
-                  jnp.asarray(indptr_in),
-                  jnp.asarray(monoids.arc_table(src_in, w_in)), g.n,
+    host = CsrAdj(indptr, src_s, dst_s, w_s, monoids.arc_table(dst_s, w_s),
+                  indptr_in, monoids.arc_table(src_in, w_in), dst_in, g.n,
                   tuple(caps))
+    host.check_sorted()
+    return jax.tree.map(jnp.asarray, host)

@@ -223,6 +223,12 @@ def count_sp_children_dense(Tw: jax.Array, A: jax.Array, *, block: int = 256
 # one table first) and one scatter per reducer (the p and c tie sums share
 # a window). The candidates and reductions are those of the per-field
 # formulation, so results are bitwise the same.
+#
+# ``sorted_seg`` tells XLA that ``seg`` is non-decreasing
+# (``indices_are_sorted`` on every scatter over it), which lets it skip
+# the sort it otherwise makes in each call. Only a container whose arcs
+# are stored in segment order sets it (``CsrAdj``, which checks the order
+# when it is built): on unsorted ids the flag gives wrong answers silently.
 
 
 def _gather_cols(rows, idx: jax.Array):
@@ -234,14 +240,17 @@ def _gather_cols(rows, idx: jax.Array):
 
 
 def _multpath_scatter(Fw: jax.Array, Fm: jax.Array, wa: jax.Array,
-                      seg: jax.Array, n: int) -> Multpath:
+                      seg: jax.Array, n: int, *, sorted_seg: bool = False
+                      ) -> Multpath:
     """Reduce the (nb, E) gathered frontier into ``seg``'s segments:
     min of ``Fw + wa``, then the tie-masked multiplicity sum."""
     cand = Fw + wa[None, :]  # (nb, E); inf + x = inf
-    minw = jax.ops.segment_min(cand.T, seg, num_segments=n).T  # (nb, n)
+    minw = jax.ops.segment_min(cand.T, seg, num_segments=n,
+                               indices_are_sorted=sorted_seg).T  # (nb, n)
     tie = (cand == minw[:, seg]) & jnp.isfinite(cand)
     m = jax.ops.segment_sum(jnp.where(tie, Fm, 0.0).T, seg,
-                            num_segments=n).T
+                            num_segments=n,
+                            indices_are_sorted=sorted_seg).T
     # segment_min of empty segments yields +inf-ish max value for floats;
     # normalize: entries with zero multiplicity are inactive.
     minw = jnp.where(m > 0, minw, INF)
@@ -249,53 +258,63 @@ def _multpath_scatter(Fw: jax.Array, Fm: jax.Array, wa: jax.Array,
 
 
 def _centpath_scatter(Fw: jax.Array, Fp: jax.Array, wa: jax.Array,
-                      alive: jax.Array, seg: jax.Array, n: int) -> Centpath:
+                      alive: jax.Array, seg: jax.Array, n: int, *,
+                      sorted_seg: bool = False) -> Centpath:
     """Reduce the (nb, E) gathered frontier into ``seg``'s segments:
     max of ``Fw - wa`` over live arcs, then the p and c tie sums in one
     scatter (c's update is the tie mask: every contributing child
     counts once)."""
     cand = jnp.where(alive[None, :] & jnp.isfinite(Fw), Fw - wa[None, :],
                      -INF)  # (nb, E)
-    maxw = jax.ops.segment_max(cand.T, seg, num_segments=n).T  # (nb, n)
+    maxw = jax.ops.segment_max(cand.T, seg, num_segments=n,
+                               indices_are_sorted=sorted_seg).T  # (nb, n)
     tie = (cand == maxw[:, seg]) & jnp.isfinite(cand)
     nb = Fw.shape[0]
     pc = jax.ops.segment_sum(
         jnp.concatenate([jnp.where(tie, Fp, 0.0), jnp.where(tie, 1.0, 0.0)]).T,
-        seg, num_segments=n).T  # (2·nb, n)
+        seg, num_segments=n, indices_are_sorted=sorted_seg).T  # (2·nb, n)
     p, c = pc[:nb], pc[nb:]
     maxw = jnp.where(c > 0, maxw, -INF)
     return Centpath(maxw, p, c)
 
 
 def multpath_relax_coo(F: Multpath, src: jax.Array, dst: jax.Array,
-                       w: jax.Array, n: int) -> Multpath:
+                       w: jax.Array, n: int, *, sorted_seg: bool = False
+                       ) -> Multpath:
     """Edge-list version of ``multpath_relax_dense``.
 
     src/dst/w: (E,) padded COO arcs (padding arcs carry w = inf).
     F.w/F.m: (nb, n). Cost O(nb * E); chunk over nb upstream if needed.
+    Segments over ``dst``; ``sorted_seg``: ``dst`` is non-decreasing.
     """
     Fw, Fm = _gather_cols([F.w, F.m], src)
-    return _multpath_scatter(Fw, Fm, w, dst, n)
+    return _multpath_scatter(Fw, Fm, w, dst, n, sorted_seg=sorted_seg)
 
 
 def centpath_relax_coo(F: Centpath, src: jax.Array, dst: jax.Array,
-                       w: jax.Array, n: int) -> Centpath:
+                       w: jax.Array, n: int, *, sorted_seg: bool = False
+                       ) -> Centpath:
     """Edge-list Brandes action: contributions flow dst -> src.
 
     For arc (v -> u, a): cand(s, v) over children u: F.w(s, u) - a.
-    Segment over ``src`` (the predecessor side).
+    Segment over ``src`` (the predecessor side); ``sorted_seg``: ``src``
+    is non-decreasing.
     """
     Fw, Fp = _gather_cols([F.w, F.p], dst)
-    return _centpath_scatter(Fw, Fp, w, jnp.isfinite(w), src, n)
+    return _centpath_scatter(Fw, Fp, w, jnp.isfinite(w), src, n,
+                             sorted_seg=sorted_seg)
 
 
 def count_sp_children_coo(Tw: jax.Array, src: jax.Array, dst: jax.Array,
-                          w: jax.Array, n: int) -> jax.Array:
-    """COO version of ``count_sp_children_dense``: segment over ``src``."""
+                          w: jax.Array, n: int, *, sorted_seg: bool = False
+                          ) -> jax.Array:
+    """COO version of ``count_sp_children_dense``: segment over ``src``
+    (``sorted_seg``: ``src`` is non-decreasing)."""
     cand = Tw[:, src] + w[None, :]  # (nb, E)
     hit = (cand == Tw[:, dst]) & jnp.isfinite(cand)
     return jax.ops.segment_sum(hit.astype(jnp.int32).T, src,
-                               num_segments=n).T
+                               num_segments=n,
+                               indices_are_sorted=sorted_seg).T
 
 
 # ---------------------------------------------------------------------------

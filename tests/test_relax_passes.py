@@ -4,11 +4,14 @@ Each distinct index vector of a relax gets one gather (fields read at the
 same index are stacked into one table) and each reducer one scatter (the
 centpath p and c sums share a window). ``test_relax_index_passes`` pins
 the count of arc-sized gathers and scatters in the lowered program of each
-of the four relax branches; the bitwise tests hold the stacked relaxes to
-the per-field formulations kept below as references.
+relax branch and of the SP-child count, and which scatters are told that
+their segment ids are sorted (``CsrAdj``'s full-edge fallback and SP-child
+count, and no other); the bitwise tests hold the stacked relaxes to the
+per-field formulations kept below as references.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 
 import jax
@@ -19,7 +22,7 @@ import pytest
 from repro.core import monoids
 from repro.core.adjacency import csr_adj_from_graph
 from repro.core.monoids import INF, Centpath, Multpath
-from repro.graphs.formats import Graph
+from repro.graphs.formats import Graph, pad_edges
 
 # ---------------------------------------------------------------------------
 # Per-field reference formulations: one gather per field read, one scatter
@@ -158,8 +161,8 @@ N, NB, VCAP, ECAP = 37, 3, 23, 96
 
 
 def _indexed_ops(fn, *args):
-    """[(op name, index count)] of every gather and scatter ``fn`` lowers
-    to (StableHLO, before any compiler pass)."""
+    """[(op name, index count, indices_are_sorted)] of every gather and
+    scatter ``fn`` lowers to (StableHLO, before any compiler pass)."""
     mod = jax.jit(fn).lower(*args).compiler_ir("stablehlo")
     found = []
 
@@ -177,8 +180,10 @@ def _indexed_ops(fn, *args):
                                             dims).group(1))
                         if ivd < len(shape):
                             shape.pop(ivd)
+                        srt = o.operation.attributes["indices_are_sorted"]
                         found.append((name.split(".")[1],
-                                      int(np.prod(shape, dtype=np.int64))))
+                                      int(np.prod(shape, dtype=np.int64)),
+                                      bool(srt.value)))
                     walk(o.operation)
 
     walk(mod.operation)
@@ -186,7 +191,11 @@ def _indexed_ops(fn, *args):
 
 
 def _count(found, kind, size):
-    return sum(1 for k, c in found if k == kind and c == size)
+    return sum(1 for k, c, _ in found if k == kind and c == size)
+
+
+def _sorted_flags(found, kind, size):
+    return {s for k, c, s in found if k == kind and c == size}
 
 
 @pytest.fixture(scope="module")
@@ -204,32 +213,58 @@ def small_csr():
     ("centpath_coo", 2, 2),
     ("multpath_rung", 4, 2),
     ("centpath_rung", 4, 2),
+    ("multpath_full_edge", 2, 2),
+    ("centpath_full_edge", 2, 2),
+    ("csr_count_sp_children", 2, 1),
+    ("coo_count_sp_children", 2, 1),
 ])
 def test_relax_index_passes(small_csr, branch, gathers, scatters):
     """One arc-sized gather per index vector, one scatter per reducer:
     COO — the stacked frontier gather and the winner gather, the
     min/max scatter and the (stacked) sum scatter; a rung adds the
     per-slot table gather and the arc table gather, and keeps its one
-    slot-sized owner scatter."""
+    slot-sized owner scatter; the SP-child count gathers at both ends
+    and scatters once. Only ``CsrAdj``'s full-edge branches (read from
+    its whole ladder, next to its rung) and its SP-child count tell XLA
+    that their arc-sized scatters' ids are sorted; the plain COO
+    helpers and the rungs do not."""
     adj, E = small_csr
     mp, cp = _frontiers(0, NB, N, 0.4)
+    coo = (adj.src, adj.dst, adj.w, N)
     if branch == "multpath_coo":
-        found = _indexed_ops(lambda F: monoids.multpath_relax_coo(
-            F, adj.src, adj.dst, adj.w, N), mp)
+        found = _indexed_ops(lambda F: monoids.multpath_relax_coo(F, *coo),
+                             mp)
     elif branch == "centpath_coo":
-        found = _indexed_ops(lambda F: monoids.centpath_relax_coo(
-            F, adj.src, adj.dst, adj.w, N), cp)
+        found = _indexed_ops(lambda F: monoids.centpath_relax_coo(F, *coo),
+                             cp)
     elif branch == "multpath_rung":
         found = _indexed_ops(lambda F: monoids.multpath_relax_csr(
             F, adj.indptr, adj.arcs, N, vcap=VCAP, ecap=ECAP), mp)
-    else:
+    elif branch == "centpath_rung":
         found = _indexed_ops(lambda F: monoids.centpath_relax_csr(
             F, adj.indptr_in, adj.arcs_in, N, vcap=VCAP, ecap=ECAP), cp)
-    size = E if branch.endswith("coo") else ECAP
+    elif branch == "multpath_full_edge":
+        found = _indexed_ops(lambda F: adj.relax_mp_stats(F)[0], mp)
+    elif branch == "centpath_full_edge":
+        found = _indexed_ops(lambda F: adj.relax_cp_stats(F)[0], cp)
+    elif branch == "csr_count_sp_children":
+        found = _indexed_ops(adj.count_sp_children, mp.w)
+    else:
+        found = _indexed_ops(lambda T: monoids.count_sp_children_coo(T, *coo),
+                             mp.w)
+    size = ECAP if branch.endswith("rung") else E
     assert _count(found, "gather", size) == gathers, found
     assert _count(found, "scatter", size) == scatters, found
-    if branch.endswith("rung"):
+    told_sorted = branch.endswith("full_edge") or branch.startswith("csr")
+    assert _sorted_flags(found, "scatter", size) == {told_sorted}, found
+    if branch.endswith("rung") or branch.endswith("full_edge"):
+        # the rung (alone, or beside the fallback in the ladder): its
+        # arc-slot scatters and its owner scatter are not sorted
         assert _count(found, "scatter", VCAP) == 1, found  # the owners
+        assert _sorted_flags(found, "scatter", VCAP) == {False}, found
+    if branch.endswith("full_edge"):
+        assert _count(found, "scatter", ECAP) == 2, found
+        assert _sorted_flags(found, "scatter", ECAP) == {False}, found
 
 
 # ---------------------------------------------------------------------------
@@ -323,3 +358,72 @@ def test_arc_tables_hold_the_sorted_arcs():
                                   np.asarray(adj.src)[order])
     np.testing.assert_array_equal(np.asarray(w_in), np.asarray(adj.w)[order])
     assert np.isinf(np.asarray(w_in)).any()
+
+
+def _shuffled(g, seed):
+    """``g``'s arcs in a random order: not the canonical (src, dst) one."""
+    perm = np.random.default_rng(seed).permutation(g.nnz)
+    return Graph(g.n, g.src[perm], g.dst[perm], g.w[perm])
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: f"seed{c[0]}")
+def test_full_edge_sorted_bitwise_matches_by_src(case):
+    """The forced fallback (``caps=((1, 1),)``) runs MFBF over the by-dst
+    arcs and MFBr over the by-src arrays, both scattering sorted ids; each
+    equals the per-field COO relax over the by-src arrays bitwise. The
+    multiplicities span 2**±12 and p is non-integer, so the tie sums round
+    and the check sees the order in which each segment adds; on the canonical
+    graph and on its arcs shuffled."""
+    seed, n, nnz, max_w, density, pad = case
+    g = _graph(seed, n, nnz, max_w)
+    mp, cp = _frontiers(seed, 4, n, density)
+    frac = 2.0 ** np.random.default_rng(seed + 2).uniform(-12, 12,
+                                                           mp.m.shape)
+    mp = Multpath(mp.w, jnp.where(mp.m > 0, mp.m * frac, 0.0)
+                  .astype(jnp.float32))
+    for graph in (g, _shuffled(g, seed)):
+        adj = csr_adj_from_graph(graph, caps=((1, 1),), pad_multiple=pad)
+        coo = (adj.src, adj.dst, adj.w, n)
+        got, st = jax.jit(adj.relax_mp_stats)(mp)
+        assert int(st.overflow) == 1
+        _assert_bitwise(got, ref_multpath_relax_coo(mp, *coo))
+        got, st = jax.jit(adj.relax_cp_stats)(cp)
+        assert int(st.overflow) == 1
+        _assert_bitwise(got, ref_centpath_relax_coo(cp, *coo))
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: f"seed{c[0]}")
+def test_csr_count_sp_children_matches_unsorted_coo(case):
+    """``CsrAdj.count_sp_children`` (by-src arcs, sorted ids) equals the
+    plain COO count over the same padded arcs in a shuffled order, on the
+    shortest-path distances from 4 sources (so that children exist)."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import dijkstra
+
+    seed, n, nnz, max_w, density, pad = case
+    g = _graph(seed, n, nnz, max_w)
+    adj = csr_adj_from_graph(g, n_b=4, pad_multiple=pad)
+    Tw = jnp.asarray(dijkstra(csr_matrix((g.w, (g.src, g.dst)), (n, n)),
+                              indices=np.arange(4)).astype(np.float32))
+    src, dst, w = pad_edges(g, multiple=pad)
+    perm = np.random.default_rng(seed).permutation(src.shape[0])
+    ref = monoids.count_sp_children_coo(
+        Tw, jnp.asarray(src[perm]), jnp.asarray(dst[perm]),
+        jnp.asarray(w[perm]), n)
+    got = jax.jit(adj.count_sp_children)(Tw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    assert int(np.asarray(ref).sum()) > 0
+
+
+def test_csr_adj_check_sorted_rejects_unsorted_ids():
+    """``csr_adj_from_graph`` checks that the ids its full-edge scatters
+    claim sorted are sorted; a hand-built container whose ``dst_in`` (or
+    ``src``) is out of order is refused."""
+    g = _graph(3, 31, 200, 1)
+    adj = csr_adj_from_graph(_shuffled(g, 3), n_b=4, pad_multiple=64)
+    adj.check_sorted()
+    for name in ("dst_in", "src"):
+        ids = np.asarray(getattr(adj, name))
+        bad = dataclasses.replace(adj, **{name: jnp.asarray(ids[::-1])})
+        with pytest.raises(ValueError, match=f"CsrAdj.{name} is not sorted"):
+            bad.check_sorted()
