@@ -7,15 +7,11 @@ from two sources:
 * the analytic §5.2/§5.3 model at Blue-Waters scale (4096 cores) for the
   paper's graphs (Orkut / LiveJournal / Patents sizes), and
 * HLO-measured per-device collective bytes of the compiled distributed BC
-  step from the dry-run artifacts (512-chip mesh), which realizes the same
-  ratio structurally.
+  step on a fake-device mesh, which realizes the same ratio structurally.
 """
 from __future__ import annotations
 
-import glob
 import math
-import json
-import os
 import time
 from typing import Dict, List
 
@@ -134,19 +130,3 @@ def measured_mesh_collectives(n: int, nb: int, iters: int,
                     if k.startswith("wire_")},
         "model_bytes": model_mesh_bytes(n_pad, nb_pad, iters, axes),
     }
-
-
-def measured_bc_collectives(dryrun_dir="results/dryrun") -> List[Dict]:
-    rows = []
-    for path in sorted(glob.glob(os.path.join(dryrun_dir,
-                                              "mfbc_paper__*.json"))):
-        rec = json.load(open(path))
-        if not rec.get("ok"):
-            continue
-        rows.append({
-            "cell": f"{rec['shape']}@{rec['mesh']}",
-            "wire_GB_per_dev": rec["collectives"]["wire_bytes"] / 1e9,
-            "msgs_per_dev": rec["collectives"]["messages"],
-            "flops_per_dev": rec["flops_per_device"],
-        })
-    return rows

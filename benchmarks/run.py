@@ -54,16 +54,12 @@ def bench_fig2_weak_scaling() -> None:
 
 
 def bench_table3_comm() -> None:
-    from benchmarks.comm_cost import measured_bc_collectives, table3_model
+    from benchmarks.comm_cost import table3_model
 
     for r in table3_model():
         _row(f"table3_model_{r['graph']}", 0.0,
              f"W2d={r['W_2d_GB']:.2f}GB;W3d={r['W_3d_GB']:.2f}GB;"
              f"ratio={r['ratio_W']:.2f};c={r['c_3d']}")
-    for r in measured_bc_collectives():
-        _row(f"table3_hlo_{r['cell']}", 0.0,
-             f"wire={r['wire_GB_per_dev']:.3f}GB/dev;"
-             f"msgs={r['msgs_per_dev']:.0f}")
 
 
 def bench_sec52_spgemm() -> None:

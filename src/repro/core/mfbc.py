@@ -4,7 +4,7 @@
 batches. The per-batch computation is a single jitted function; the batch
 loop runs on the host, which is also where fault tolerance lives — the λ
 accumulator plus the batch index *is* the checkpoint (see
-``repro.train.checkpoint``).
+``repro.launch.checkpoint``).
 """
 from __future__ import annotations
 

@@ -52,7 +52,7 @@ from repro.core import brandes_bc, cc_ref, closeness_ref, khop_ref
 from repro.graphs.generators import from_spec
 from repro.launch.mesh import mesh_from_spec
 from repro.launch.runtime import enable_compile_cache
-from repro.train import checkpoint as ckpt_lib
+from repro.launch import checkpoint as ckpt_lib
 
 
 def _query_from_args(args, mode: str, **kw) -> BCQuery:

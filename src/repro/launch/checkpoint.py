@@ -12,10 +12,9 @@ Design (no orbax/tensorstore dependency — built from scratch):
   elastic scale up/down.
 * ``latest_step(dir)`` / retention — the restart loop's entry point.
 
-For BC runs the checkpoint is tiny (λ accumulator + batch index); for
-training it is params + optimizer state + step + data-pipeline position.
-Deterministic pipelines keyed by step make restarts bit-exact
-(``tests/test_fault_tolerance.py``).
+For BC runs (``launch/bc_run.py --ckpt-dir``) the checkpoint is tiny: the
+cumulative λ accumulator, the batch index and the batch size
+(``tests/test_checkpoint.py``).
 """
 from __future__ import annotations
 

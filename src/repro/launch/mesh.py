@@ -1,13 +1,11 @@
-"""Mesh construction helpers (production, debug, and CLI-spec meshes).
+"""Mesh construction from CLI specs.
 
 Functions only, and jax is imported lazily *inside* them, so importing
 this module never touches jax device state — callers that must set
 ``XLA_FLAGS`` (fake host devices) before jax initializes can import the
 jax-free ``parse_mesh_spec`` first (``benchmarks/bc_approx.py`` does).
 
-Production: 16x16 = 256 chips (data, model); multi-pod: 2 pods x 256 =
-512 chips (pod, data, model). The ``pod`` axis is MFBC's replication
-factor c (DESIGN.md §4) and plain DP for the LM archs.
+The ``pod`` axis is MFBC's replication factor c (DESIGN.md §4).
 
 ``mesh_from_spec("DxM" | "PxDxM")`` is the shared CLI entry point
 (``launch.bc_run --mesh``, benchmarks): 2 sizes map (data, model),
@@ -17,23 +15,6 @@ device count.
 from __future__ import annotations
 
 from typing import Tuple
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(*, multi_pod: bool = False):
-    """Tiny mesh for CI-scale multi-device runs (8 host devices)."""
-    import jax
-
-    shape = (2, 2, 2) if multi_pod else (4, 2)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def parse_mesh_spec(spec: str) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:

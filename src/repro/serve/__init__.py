@@ -13,9 +13,6 @@ Three layers, outermost first:
 * ``bc_service`` — the solver loop: ``BCService`` tick-scheduling
   ``BCRequest``s over slot-fused adaptive sampling, retiring
   ``BCResponse``s (JSON round-trippable, optionally checkpointed).
-
-``engine.ServeEngine`` is the earlier single-graph serving loop, kept
-for its tests; new code should front ``BCService``.
 """
 from repro.serve.bc_service import BCRequest, BCResponse, BCService
 from repro.serve.cache import HIT, MISS, REFINE, CacheEntry, ResultCache

@@ -1,14 +1,13 @@
 """Top-k central-vertices serving endpoint over approximate BC.
 
-The request/response scheduling mirrors ``serve.engine.ServeEngine``: a
-fixed pool of ``n_slots`` concurrently progressing jobs, an admission
-queue, and a host-side ``step()`` tick that advances active slots by
-units of work — here *sampling epochs* of the adaptive approximate-BC
-driver instead of decode tokens. Long-running queries (tight ε on a big
-graph) therefore never block short ones (loose ε / top-k early exit): a
-slot frees the moment its estimator converges.
+The request/response scheduling is a fixed pool of ``n_slots``
+concurrently progressing jobs, an admission queue, and a host-side
+``step()`` tick that advances active slots by units of work: *sampling
+epochs* of the adaptive approximate-BC driver. Long-running queries
+(tight ε on a big graph) therefore never block short ones (loose ε /
+top-k early exit): a slot frees the moment its estimator converges.
 
-Graphs are registered up front (like model weights); the unified
+Graphs are registered up front; the unified
 ``repro.bc`` planner resolves each one to a capacity ``BCPlan`` and a
 shared ``BatchExecutor`` — jitted batch step plus device-resident
 adjacency — reused by every request that names the graph. On top of

@@ -233,7 +233,7 @@ def test_bc_run_checkpoint_resume(tmp_path, restore_compile_cache):
     import shutil
 
     from repro.launch import bc_run
-    from repro.train import checkpoint as ckpt_lib
+    from repro.launch import checkpoint as ckpt_lib
 
     ck = str(tmp_path / "ck")
     args = ["--graph", "rmat", "--scale", "5", "--nb", "8",

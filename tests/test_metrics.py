@@ -24,6 +24,7 @@ try:
 except ImportError:  # deterministic sweep, see tests/_hypothesis_fallback.py
     from _hypothesis_fallback import given, settings, strategies as st
 
+from _graphs import GRAPHS
 from repro.bc import (BatchAssembler, BCQuery, ExecutionConfig, build_executor,
                       fuse_group, metric_spec, plan, registered_metrics,
                       scatter, solve)
@@ -149,6 +150,27 @@ def test_metric_parity_on_random_rmat_all_backends(case):
         else:
             np.testing.assert_allclose(lam, ref, rtol=1e-4, atol=1e-5,
                                        err_msg=backend)
+
+
+@pytest.mark.parametrize("gname", sorted(GRAPHS))
+@pytest.mark.parametrize("metric", ["closeness", "khop:2", "components"])
+def test_metrics_match_oracles_on_fixed_graphs(metric, gname):
+    """The parity above on the fixed oracle graphs: directed, weighted,
+    a 79-hop path past the trace cap and a star, on every backend."""
+    g = GRAPHS[gname]()
+    name, _, hops = metric.partition(":")
+    for backend in BACKENDS:
+        q = BCQuery(mode="exact", metric=name, hops=int(hops or 0),
+                    execution=ExecutionConfig(backend=backend))
+        lam = solve(g, q, plan=plan(g, q, n_devices=1)).lam
+        if name == "closeness":
+            np.testing.assert_allclose(lam, closeness_ref(g), rtol=1e-4,
+                                       atol=1e-5, err_msg=backend)
+        elif name == "khop":
+            np.testing.assert_array_equal(lam, khop_ref(g, hops=int(hops)),
+                                          err_msg=backend)
+        else:
+            np.testing.assert_array_equal(lam, cc_ref(g), err_msg=backend)
 
 
 def test_components_labels_bitwise_union_find():

@@ -2,42 +2,30 @@
 
 Covers paper Lemma 4.1 (MFBF distances + multiplicities), Lemma 4.2 (MFBr
 partial centrality factors), and Theorem 4.3 (full λ), on directed and
-undirected, weighted and unweighted graphs, in both the dense and the COO
-relaxation regimes.
+undirected, weighted and unweighted graphs, through the dense, COO and
+frontier-CSR relaxation backends.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _graphs import GRAPHS
 from repro.core import (brandes_bc, bfs_bc, coo_adj_from_graph,
-                        dense_adj_from_graph, mfbc, mfbf, mfbr)
-from repro.core.mfbc import mfbc_batch
-from repro.graphs.generators import (erdos_renyi, path_graph, ring_of_cliques,
-                                     rmat, uniform_random)
+                        dense_adj_from_graph, mfbc, mfbf)
+from repro.core.adjacency import csr_adj_from_graph
+from repro.graphs.generators import path_graph, ring_of_cliques
 
 
 def _adj(g, backend):
-    return dense_adj_from_graph(g) if backend == "dense" else coo_adj_from_graph(g)
+    if backend == "dense":
+        return dense_adj_from_graph(g)
+    if backend == "csr":
+        return csr_adj_from_graph(g, n_b=16)
+    return coo_adj_from_graph(g)
 
 
-GRAPHS = {
-    "path8": lambda: path_graph(8),
-    "path8_w": lambda: path_graph(8, weighted=True, seed=3),
-    "roc4x4": lambda: ring_of_cliques(4, 4),
-    "roc3x5_w": lambda: ring_of_cliques(3, 5, weighted=True, seed=1),
-    "er40": lambda: erdos_renyi(40, 0.15, seed=7),
-    "er40_w": lambda: erdos_renyi(40, 0.15, seed=7, weighted=True, max_weight=9),
-    "er40_dir_w": lambda: erdos_renyi(40, 0.12, seed=11, weighted=True,
-                                      max_weight=7, directed=True),
-    "rmat5": lambda: rmat(5, 4, seed=5),
-    "rmat5_dir_w": lambda: rmat(5, 3, seed=9, weighted=True, max_weight=5,
-                                directed=True),
-    "uni60": lambda: uniform_random(60, 6.0, seed=13),
-}
-
-
-@pytest.mark.parametrize("backend", ["dense", "coo"])
+@pytest.mark.parametrize("backend", ["dense", "coo", "csr"])
 @pytest.mark.parametrize("gname", sorted(GRAPHS))
 def test_mfbf_matches_dijkstra(gname, backend):
     """Lemma 4.1: T(s, v) = (τ(s, v), σ̄(s, v))."""
@@ -59,7 +47,7 @@ def test_mfbf_matches_dijkstra(gname, backend):
     np.testing.assert_allclose(Tm, sigma_ref, rtol=1e-6)
 
 
-@pytest.mark.parametrize("backend", ["dense", "coo"])
+@pytest.mark.parametrize("backend", ["dense", "coo", "csr"])
 @pytest.mark.parametrize("gname", sorted(GRAPHS))
 def test_mfbc_matches_brandes(gname, backend):
     """Theorem 4.3: λ(v) = Σ_{s,t} σ(s,t,v)/σ̄(s,t)."""
