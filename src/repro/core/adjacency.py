@@ -189,7 +189,9 @@ class CsrAdj:
     active in any batch row) and its incident arcs, picks the smallest
     bucket that fits with ``lax.switch``, and falls back to the
     full-edge-list COO relax when every bucket overflows — so results
-    never depend on the ladder, only the work does.
+    never depend on the ladder, only the work does. MFBF switches over
+    every rung; MFBr over the first ``cp_rungs`` (``centpath_rungs``),
+    and its fallback still reports ``bucket = len(caps)``.
     """
 
     indptr: jax.Array  # (n+1,) int32 row pointers into the by-src arrays
@@ -202,15 +204,16 @@ class CsrAdj:
     dst_in: jax.Array  # (E,) int32 by-dst arc heads, sorted ascending
     n_static: int
     caps: Tuple[Tuple[int, int], ...]
+    cp_rungs: int  # the rungs of ``caps`` that MFBr's relax switches over
 
     def tree_flatten(self):
         return ((self.indptr, self.src, self.dst, self.w, self.arcs,
                  self.indptr_in, self.arcs_in, self.dst_in),
-                (self.n_static, self.caps))
+                (self.n_static, self.caps, self.cp_rungs))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, aux[0], aux[1])
+        return cls(*children, *aux)
 
     @property
     def n(self) -> int:
@@ -231,14 +234,16 @@ class CsrAdj:
                                     sources)
 
     @jax.named_scope("relax.pick")
-    def _pick_bucket(self, Fw: jax.Array, indptr: jax.Array):
+    def _pick_bucket(self, Fw: jax.Array, indptr: jax.Array,
+                     caps: Tuple[Tuple[int, int], ...]):
         """Count the union-column frontier (entries where ``Fw`` is
-        finite) and choose the smallest fitting bucket. ``nnz`` is active
-        *columns* (vertices live in any batch row — what the compacting
-        relaxes expand), ``arcs`` their incident arc total, ``slots`` the
-        arc slots the chosen branch processes, ``entry_arcs`` the arcs of
-        every active (row, vertex) entry (from the same per-column count
-        that marks the union columns)."""
+        finite) and choose the smallest fitting bucket of ``caps``, a
+        prefix of ``self.caps``; past it, ``len(self.caps)``. ``nnz`` is
+        active *columns* (vertices live in any batch row — what the
+        compacting relaxes expand), ``arcs`` their incident arc total,
+        ``slots`` the arc slots the chosen branch processes,
+        ``entry_arcs`` the arcs of every active (row, vertex) entry (from
+        the same per-column count that marks the union columns)."""
         deg = indptr[1:] - indptr[:-1]
         per_col = jnp.sum(jnp.isfinite(Fw), axis=0, dtype=jnp.int32)
         colmask = per_col > 0
@@ -246,22 +251,24 @@ class CsrAdj:
         arcs = jnp.sum(jnp.where(colmask, deg, 0)).astype(jnp.int32)
         entry_arcs = jnp.sum(per_col * deg, dtype=jnp.int32)
         bucket = jnp.int32(len(self.caps))
-        for i in reversed(range(len(self.caps))):
-            vcap, ecap = self.caps[i]
+        for i in reversed(range(len(caps))):
+            vcap, ecap = caps[i]
             fits = (nnz <= vcap) & (arcs <= ecap)
             bucket = jnp.where(fits, jnp.int32(i), bucket)
         slots = jnp.asarray([e for _, e in self.caps] + [self.src.shape[0]],
                             jnp.int32)[bucket]
         return nnz, arcs, bucket, slots, entry_arcs
 
-    def _switch(self, F, indptr, rung, full_edge):
-        """Run ``F`` through the smallest fitting rung (``rung(vcap,
-        ecap)``) or, past the ladder, ``full_edge``; with its stats."""
-        nnz, arcs, bucket, slots, entry_arcs = self._pick_bucket(F.w, indptr)
+    def _switch(self, F, indptr, caps, rung, full_edge):
+        """Run ``F`` through the smallest fitting rung of ``caps``
+        (``rung(vcap, ecap)``) or, past them, ``full_edge``; with its
+        stats."""
+        nnz, arcs, bucket, slots, entry_arcs = self._pick_bucket(
+            F.w, indptr, caps)
         branches = [_scoped(f"relax.rung{i}", rung(v, e))
-                    for i, (v, e) in enumerate(self.caps)]
+                    for i, (v, e) in enumerate(caps)]
         branches.append(_scoped("relax.full_edge", full_edge))
-        out = jax.lax.switch(bucket, branches, F)
+        out = jax.lax.switch(jnp.minimum(bucket, len(caps)), branches, F)
         overflow = (bucket == len(self.caps)).astype(jnp.int32)
         return out, RelaxStats(nnz, arcs, bucket, overflow, slots,
                                entry_arcs)
@@ -278,7 +285,7 @@ class CsrAdj:
 
     def relax_mp_stats(self, F: Multpath) -> Tuple[Multpath, RelaxStats]:
         return self._switch(
-            F, self.indptr,
+            F, self.indptr, self.caps,
             lambda v, e: functools.partial(
                 monoids.multpath_relax_csr, indptr=self.indptr,
                 arcs=self.arcs, n=self.n, vcap=v, ecap=e),
@@ -286,7 +293,7 @@ class CsrAdj:
 
     def relax_cp_stats(self, F: Centpath) -> Tuple[Centpath, RelaxStats]:
         return self._switch(
-            F, self.indptr_in,
+            F, self.indptr_in, self.caps[:self.cp_rungs],
             lambda v, e: functools.partial(
                 monoids.centpath_relax_csr, indptr_in=self.indptr_in,
                 arcs_in=self.arcs_in, n=self.n, vcap=v, ecap=e),
@@ -304,18 +311,31 @@ class CsrAdj:
                                              self.n, sorted_seg=True)
 
 
+# What a centpath (MFBr) rung slot costs, in centpath full-edge arcs.
+# Every slot of a rung is processed, live or not, so a rung costs its
+# ``ecap`` times this against ``m`` for the fallback. Measured per call
+# inside the sweep on TPU v5e, R-MAT SCALE 18 (m = 7,610,770), against a
+# 216 ms fallback: rung 2 (4,194,304 slots) 355-360 ms, 2.98-3.02; rung 1
+# (1,048,576) 90 ms, 3.01-3.02; rung 0 (262,144) 24-25 ms, 3.19-3.30.
+CENTPATH_SLOT_COST = 3.0
+
+
 def frontier_caps(n_b: int, n: int, m: int) -> Tuple[Tuple[int, int], ...]:
     """Power-of-two ``(vcap, ecap)`` escalation ladder for compaction.
 
     ``vcap`` bounds the compacted union-frontier *columns* (vertices
-    active in any batch row), ``ecap`` their incident arc slots. A
-    compact relax costs ``n_b * ecap`` candidate work plus an O(n)
-    compaction, against ``n_b * m`` for the full COO fallback — so the
+    active in any batch row), ``ecap`` their incident arc slots. The
     ladder's ecaps climb power-of-two from ~m/32 and stop short of
     ``m``, letting the fallback absorb saturated frontiers (typically
     the 1–3 mid-sweep iterations) while the compact buckets win the
     launch and drain phases. ``vcap = n`` on every rung: column count
     never overflows, only arc volume escalates.
+
+    A rung is not ``n_b * ecap`` work against ``n_b * m``: its compaction
+    and expansion make a slot dearer than a fallback arc, about three
+    fallback arcs of its own relax kind on TPU v5e. A centpath slot costs
+    ``CENTPATH_SLOT_COST`` arcs, so MFBr takes only ``centpath_rungs`` of
+    this ladder; MFBF switches over every rung.
     """
     full_e = max(m, 1)
     caps = []
@@ -328,6 +348,16 @@ def frontier_caps(n_b: int, n: int, m: int) -> Tuple[Tuple[int, int], ...]:
     if not caps:
         caps.append((int(n), int(full_e)))
     return tuple(caps)
+
+
+def centpath_rungs(caps: Tuple[Tuple[int, int], ...], m: int) -> int:
+    """How many rungs of ``caps`` cost MFBr less than its full-edge
+    fallback over ``m`` arcs: the prefix with ``ecap *
+    CENTPATH_SLOT_COST < m``."""
+    k = 0
+    while k < len(caps) and caps[k][1] * CENTPATH_SLOT_COST < m:
+        k += 1
+    return k
 
 
 def dense_adj_from_graph(g: Graph, *, block: int = 512,
@@ -349,8 +379,9 @@ def csr_adj_from_graph(g: Graph, *, n_b: int = 64,
     The by-dst order is the by-src order stably sorted by dst, so within
     each dst the arcs keep their by-src order.
     ``n_b`` sizes the default capacity ladder (it bounds the batch axis
-    of the frontiers the relaxes will see); pass explicit ``caps`` to
-    override — tests force escalation with caps like ``((1, 1),)``.
+    of the frontiers the relaxes will see), of which MFBr keeps the
+    ``centpath_rungs``; explicit ``caps`` serve both relaxes as given —
+    tests force escalation with caps like ``((1, 1),)``.
     """
     src, dst, w = pad_edges(g, multiple=pad_multiple)
     order = np.argsort(src, kind="stable")
@@ -364,9 +395,13 @@ def csr_adj_from_graph(g: Graph, *, n_b: int = 64,
     np.add.at(indptr_in, dst_in + 1, 1)
     np.cumsum(indptr_in, out=indptr_in)
     if caps is None:
-        caps = frontier_caps(n_b, g.n, int(src_s.shape[0]))
+        m = int(src_s.shape[0])
+        caps = frontier_caps(n_b, g.n, m)
+        cp_rungs = centpath_rungs(caps, m)
+    else:
+        cp_rungs = len(caps)
     host = CsrAdj(indptr, src_s, dst_s, w_s, monoids.arc_table(dst_s, w_s),
                   indptr_in, monoids.arc_table(src_in, w_in), dst_in, g.n,
-                  tuple(caps))
+                  tuple(caps), cp_rungs)
     host.check_sorted()
     return jax.tree.map(jnp.asarray, host)

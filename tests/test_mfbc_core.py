@@ -14,6 +14,8 @@ from _graphs import GRAPHS
 from repro.core import (brandes_bc, bfs_bc, coo_adj_from_graph,
                         dense_adj_from_graph, mfbc, mfbf)
 from repro.core.adjacency import csr_adj_from_graph
+from repro.core.mfbc import mfbc_batch_moments_traced
+from repro.core.monoids import INF, Centpath, Multpath
 from repro.graphs.generators import path_graph, ring_of_cliques
 
 
@@ -55,6 +57,52 @@ def test_mfbc_matches_brandes(gname, backend):
     lam_ref = brandes_bc(g)
     lam = mfbc(g, n_b=8, backend=backend)
     np.testing.assert_allclose(lam, lam_ref, rtol=1e-5, atol=1e-8)
+
+
+def _columns_with_arcs(indptr, lo, hi):
+    """A vertex set whose arcs (by ``indptr``) number in ``(lo, hi]``."""
+    deg = np.diff(np.asarray(indptr))
+    cols, total = np.zeros(deg.shape[0], bool), 0
+    for v in np.argsort(-deg, kind="stable"):
+        if total > lo:
+            break
+        if total + deg[v] <= hi:
+            cols[v], total = True, total + deg[v]
+    assert lo < total <= hi, (lo, total, hi)
+    return jnp.asarray(cols)
+
+
+@pytest.mark.parametrize("gname", ["er40_w", "roc4x4", "star33"])
+def test_csr_default_ladder_mfbr_falls_back_where_mfbf_has_a_rung(gname):
+    """Through the default ladder MFBr keeps only the rungs cheaper than
+    its full-edge fallback. A frontier whose union arcs fit MFBF's top
+    rung but not MFBr's runs MFBr's fallback (``bucket = len(caps)``,
+    ``overflow = 1``, every arc a slot) and MFBF's rung; the sweeps take
+    such fallbacks, and λ still matches Brandes."""
+    g = GRAPHS[gname]()  # undirected: in-arcs and out-arcs agree
+    adj = csr_adj_from_graph(g, n_b=16)
+    caps, E = adj.caps, int(adj.src.shape[0])
+    assert 0 < adj.cp_rungs < len(caps)
+    cols = _columns_with_arcs(adj.indptr_in, caps[adj.cp_rungs - 1][1],
+                              caps[-1][1])
+    on = cols.astype(jnp.float32)[None, :]
+    _, st = jax.jit(adj.relax_cp_stats)(
+        Centpath(jnp.where(cols, 0.0, -INF)[None, :], on, on))
+    assert (int(st.bucket), int(st.overflow), int(st.slots)) == (
+        len(caps), 1, E)
+    _, st = jax.jit(adj.relax_mp_stats)(
+        Multpath(jnp.where(cols, 0.0, INF)[None, :], on))
+    assert adj.cp_rungs <= int(st.bucket) < len(caps)
+    assert int(st.overflow) == 0
+
+    src = jnp.arange(16, dtype=jnp.int32)
+    *_, tr_br = mfbc_batch_moments_traced(adj, src, jnp.ones(16, bool))
+    k = int(tr_br.iters)
+    rows = list(zip(np.asarray(tr_br.bucket)[:k], np.asarray(tr_br.arcs)[:k]))
+    assert any(b == len(caps) and a <= caps[-1][1] for b, a in rows), rows
+    assert int(tr_br.overflows) == sum(b == len(caps) for b, _ in rows)
+    np.testing.assert_allclose(mfbc(g, n_b=16, backend="csr"), brandes_bc(g),
+                               rtol=1e-5, atol=1e-8)
 
 
 @pytest.mark.parametrize("gname", ["path8", "roc4x4", "er40", "rmat5", "uni60"])

@@ -19,10 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import monoids
-from repro.core.adjacency import csr_adj_from_graph
+from repro.core import brandes_bc, mfbc, monoids
+from repro.core.adjacency import (CENTPATH_SLOT_COST, csr_adj_from_graph,
+                                  frontier_caps)
 from repro.core.monoids import INF, Centpath, Multpath
 from repro.graphs.formats import Graph, pad_edges
+from repro.graphs.generators import path_graph
 
 # ---------------------------------------------------------------------------
 # Per-field reference formulations: one gather per field read, one scatter
@@ -340,6 +342,105 @@ def test_csr_ladder_bitwise_matches_per_field(case):
         _assert_bitwise(got, ref_centpath_relax_coo(cp, *coo))
         if adj.caps == ((1, 1),):
             assert int(st_mp.overflow) == int(st_cp.overflow) == 1
+
+
+# ---------------------------------------------------------------------------
+# The capacity ladder of each relax kind
+# ---------------------------------------------------------------------------
+
+
+def _rung_scopes(fn, F):
+    """The ``relax.rung<i>`` indices, and whether ``relax.full_edge`` is
+    there, in the lowered program of ``fn(F)``."""
+    hlo = jax.jit(fn).lower(F).as_text(debug_info=True)
+    return ({int(i) for i in re.findall(r"relax\.rung(\d+)", hlo)},
+            "relax.full_edge" in hlo)
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda c: f"seed{c[0]}")
+def test_default_ladder_gives_mfbr_the_rungs_cheaper_than_its_fallback(case):
+    """With the default ladder MFBF switches over every rung and MFBr over
+    the prefix whose rungs have ``ecap · CENTPATH_SLOT_COST < E``: a
+    strict prefix wherever the top rung misses that, and each relax still
+    equals the per-field COO relax bitwise."""
+    seed, n, nnz, max_w, density, pad = case
+    adj = csr_adj_from_graph(_graph(seed, n, nnz, max_w), n_b=4,
+                             pad_multiple=pad)
+    E, caps = int(adj.src.shape[0]), adj.caps
+    assert caps == frontier_caps(4, n, E)
+    kept = [e * CENTPATH_SLOT_COST < E for _, e in caps]
+    assert adj.cp_rungs == sum(kept) and all(kept[:adj.cp_rungs])
+    assert (adj.cp_rungs < len(caps)) == (caps[-1][1] * CENTPATH_SLOT_COST
+                                          >= E)
+    mp, cp = _frontiers(seed, 4, n, density)
+    assert _rung_scopes(adj.relax_mp_stats, mp) == (set(range(len(caps))),
+                                                    True)
+    assert _rung_scopes(adj.relax_cp_stats, cp) == (set(range(adj.cp_rungs)),
+                                                    True)
+    coo = (adj.src, adj.dst, adj.w, n)
+    _assert_bitwise(jax.jit(adj.relax_cp_stats)(cp)[0],
+                    ref_centpath_relax_coo(cp, *coo))
+
+
+@pytest.mark.parametrize("caps", [((1, 1),), ((64, 32), (64, 256)),
+                                  ((31, 192),)],
+                         ids=["overflow", "two-rung", "one-full-rung"])
+def test_explicit_caps_reach_both_relaxes(caps):
+    """An explicit ladder serves MFBF and MFBr as given, even a rung the
+    default ladder would drop for MFBr (``((31, 192),)``: ecap = E)."""
+    seed, n, nnz, max_w, density, pad = CASES[3]
+    adj = csr_adj_from_graph(_graph(seed, n, nnz, max_w), caps=caps,
+                             pad_multiple=pad)
+    E = int(adj.src.shape[0])
+    assert adj.caps == caps and adj.cp_rungs == len(caps)
+    mp, cp = _frontiers(seed, 4, n, density)
+    rungs = (set(range(len(caps))), True)
+    assert _rung_scopes(adj.relax_mp_stats, mp) == rungs
+    assert _rung_scopes(adj.relax_cp_stats, cp) == rungs
+    if caps[-1][1] >= E:  # every frontier fits: a rung serves MFBr too
+        _, st = jax.jit(adj.relax_cp_stats)(cp)
+        assert int(st.bucket) < len(caps) and int(st.overflow) == 0
+
+
+def _tiny_graphs():
+    """Graphs whose default ladder is one 2-slot rung that costs MFBr
+    more than its fallback over E <= 5 arcs."""
+    ring = Graph(3, np.array([0, 1, 2]), np.array([1, 2, 0]),
+                 np.array([1.0, 2.0, 1.0], np.float32), directed=True)
+    chord = Graph(4, np.array([0, 1, 2, 3, 0]), np.array([1, 2, 3, 0, 2]),
+                  np.array([1.0, 1.0, 2.0, 1.0, 2.0], np.float32),
+                  directed=True)
+    return {"path3": path_graph(3), "ring3": ring, "ring4_chord": chord}
+
+
+@pytest.mark.parametrize("gname", ["path3", "ring3", "ring4_chord"])
+def test_mfbr_with_no_rung_relaxes_through_the_fallback(gname):
+    """When pruning leaves MFBr no rung, its switch holds the fallback
+    alone: every relax reports ``bucket = len(caps)``, ``overflow = 1``
+    and ``slots = E``, equals the per-field COO relax bitwise, and λ
+    matches Brandes; MFBF still serves a one-column frontier from its
+    rung."""
+    g = _tiny_graphs()[gname]
+    adj = csr_adj_from_graph(g, n_b=4)
+    E, n = int(adj.src.shape[0]), g.n
+    assert adj.caps == ((n, 2),) and adj.cp_rungs == 0
+    _, cp = _frontiers(0, 4, n, 0.6)
+    assert _rung_scopes(adj.relax_cp_stats, cp) == (set(), True)
+    coo = (adj.src, adj.dst, adj.w, n)
+    for col in range(n):
+        one = jnp.arange(n) == col
+        F = Centpath(jnp.where(one, 0.0, -INF)[None, :],
+                     one.astype(jnp.float32)[None, :],
+                     one.astype(jnp.float32)[None, :])
+        got, st = jax.jit(adj.relax_cp_stats)(F)
+        assert (int(st.bucket), int(st.overflow), int(st.slots)) == (1, 1, E)
+        _assert_bitwise(got, ref_centpath_relax_coo(F, *coo))
+        M = Multpath(jnp.where(one, 0.0, INF)[None, :],
+                     one.astype(jnp.float32)[None, :])
+        _, st = jax.jit(adj.relax_mp_stats)(M)
+        assert (int(st.bucket), int(st.overflow)) == (0, 0)
+    np.testing.assert_allclose(mfbc(g, n_b=4, backend="csr"), brandes_bc(g),
+                               rtol=1e-5, atol=1e-8)
 
 
 def test_arc_tables_hold_the_sorted_arcs():

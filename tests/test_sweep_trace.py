@@ -51,16 +51,18 @@ def _masks(step, state, nact_at):
     return masks
 
 
-def _recount(masks, indptr, caps, n_arcs):
+def _recount(masks, indptr, caps, n_arcs, rungs=None):
     """(fnnz, rung, arcs, slots, entry_arcs) of each relax, from its
-    frontier mask."""
+    frontier mask; the relax switches over the first ``rungs`` of
+    ``caps`` (all of them by default)."""
     indptr = np.asarray(indptr)
     deg = indptr[1:] - indptr[:-1]
+    kept = caps[:rungs]
     rows = []
     for m in masks:
         cols = m.any(axis=0)
         nnz, arcs = int(cols.sum()), int(deg[cols].sum())
-        rung = next((i for i, (v, e) in enumerate(caps)
+        rung = next((i for i, (v, e) in enumerate(kept)
                      if nnz <= v and arcs <= e), len(caps))
         slots = caps[rung][1] if rung < len(caps) else n_arcs
         rows.append((int(m.sum()), rung, arcs, slots,
@@ -104,7 +106,7 @@ def test_trace_counts_match_a_host_recount(graph, caps):
     Tm_safe, finite, state = R._init(adj, Tw, Tm)
     step_br = jax.jit(lambda s: R._step(adj, Tw, Tm_safe, finite, s))
     rows_br = _recount(_masks(step_br, state, 4), adj.indptr_in, caps,
-                       n_arcs)
+                       n_arcs, adj.cp_rungs)
     _check(tr_br, rows_br, caps)
     if caps == ((1, 1),):
         # a frontier of one column with one arc still fits the lone rung
@@ -141,7 +143,7 @@ def test_entry_arcs_and_reached_match_a_host_recount(graph, caps):
     Tm_safe, finite, state = R._init(adj, Tw, Tm)
     step_br = jax.jit(lambda s: R._step(adj, Tw, Tm_safe, finite, s))
     rows_br = _recount(_masks(step_br, state, 4), adj.indptr_in, caps,
-                       n_arcs)
+                       n_arcs, adj.cp_rungs)
     k = int(tr_br.iters)
     assert np.asarray(tr_br.entry_arcs)[:k].tolist() == [r[4] for r in
                                                          rows_br]
@@ -190,7 +192,7 @@ def test_unit_weights_enter_each_entry_once(caps):
                                     + (fin * deg).sum())
 
 
-def _pick_without_counts(self, Fw, indptr):
+def _pick_without_counts(self, Fw, indptr, caps):
     """``CsrAdj._pick_bucket`` before the entry counts: the union columns
     from an ``any`` and no ``entry_arcs``."""
     deg = indptr[1:] - indptr[:-1]
@@ -198,8 +200,8 @@ def _pick_without_counts(self, Fw, indptr):
     nnz = jnp.sum(colmask.astype(jnp.int32))
     arcs = jnp.sum(jnp.where(colmask, deg, 0)).astype(jnp.int32)
     bucket = jnp.int32(len(self.caps))
-    for i in reversed(range(len(self.caps))):
-        vcap, ecap = self.caps[i]
+    for i in reversed(range(len(caps))):
+        vcap, ecap = caps[i]
         bucket = jnp.where((nnz <= vcap) & (arcs <= ecap), jnp.int32(i),
                            bucket)
     slots = jnp.asarray([e for _, e in self.caps] + [self.src.shape[0]],
